@@ -14,6 +14,7 @@ import statistics
 from pathlib import Path
 from typing import Dict, List
 
+from repro.analysis.roofline import DRYRUN_DEVICE_KIND
 from repro.jigsaw.costmodel import profile_db, v100_profiles
 from repro.jigsaw.schedulers import ALL_SCHEDULERS, JigsawScheduler
 from repro.jigsaw.simulator import simulate
@@ -25,7 +26,8 @@ OUT = Path(__file__).resolve().parents[1] / "BENCH_fig4_scheduler.json"
 def bench(num_jobs: int = 150, machines: int = 45, seed: int = 1,
           mean_arrival: float = 2.0, use_hlo_profiles: bool = False
           ) -> Dict[str, dict]:
-    db = profile_db() if use_hlo_profiles else v100_profiles()
+    db = (profile_db(DRYRUN_DEVICE_KIND) if use_hlo_profiles
+          else v100_profiles())
     kw = dict(num_jobs=num_jobs, seed=seed, db=db,
               mean_arrival_s=mean_arrival, min_iters=100, max_iters=500)
     jobs_spb = generate_trace(spb=True, **kw)

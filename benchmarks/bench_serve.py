@@ -34,13 +34,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis.roofline import HBM_BW, decode_bandwidth_bound
+from repro.analysis.roofline import decode_bandwidth_bound, peaks
 from repro.configs import reduced_config
 from repro.data.pipeline import MarkovLM
 from repro.models import lm
 from repro.serve import ServeEngine, default_geometry
 
 OUT = Path(__file__).resolve().parents[1] / "BENCH_serve.json"
+# the decode roofline is priced for this chip, not the host that runs here
+ROOFLINE_KIND = "TPU v5 lite"
 
 
 def _trace(args):
@@ -185,6 +187,7 @@ def main():
         rows.append(bench_continuous(cfg, params, trace, args))
         rows.append(bench_static(cfg, params, trace, args))
 
+    hbm_bw = peaks(ROOFLINE_KIND).hbm_bw
     rec = {
         "arch": args.arch, "requests": args.requests,
         "prompt_len": args.prompt_len, "max_new": args.max_new,
@@ -195,9 +198,11 @@ def main():
         "platform": platform.platform(),
         "jax_version": jax.__version__,
         "roofline": {
-            "hbm_bw": HBM_BW,
+            "device_kind": ROOFLINE_KIND,
+            "hbm_bw": hbm_bw,
             "decode_tokens_per_s_bound": round(decode_bandwidth_bound(
-                cfg, args.slots, args.max_context), 2),
+                cfg, args.slots, args.max_context,
+                bw=hbm_bw), 2),
         },
         "rows": rows,
     }

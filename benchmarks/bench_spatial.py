@@ -25,6 +25,10 @@ Two claims, two parts, one ``BENCH_spatial.json``:
   one core per submesh the same harness measures near-2x.
 
   PYTHONPATH=src python benchmarks/bench_spatial.py [--full]
+
+Every number here is a CPU timing, never a chip measurement: the script
+exits non-zero when JAX picks any backend but the CPU, and its children
+inherit the parent's platform.
 """
 from __future__ import annotations
 
@@ -83,11 +87,11 @@ def bench_warmup(counts, *, shared: bool) -> dict:
     return points
 
 
-def _cluster_cmd(iters: int, json_out: str, cc_dir: str, spatial: bool):
+def _cluster_cmd(iters: int, json_out: str, spatial: bool):
     cmd = [sys.executable, "-m", "repro.launch.cluster",
            "--jobs", "2", "--machines", "2", "--workers", "1",
            "--iters", str(iters), "--arrival", "0.0", "--quiet",
-           "--compilation-cache-dir", cc_dir, "--json-out", json_out]
+           "--json-out", json_out]
     if spatial:
         cmd.append("--spatial")
     return cmd
@@ -97,17 +101,18 @@ def bench_modes(iters: int, reps: int = 2) -> dict:
     """Median warm-run aggregate steps/s: spatial vs time-multiplex."""
     env = {**os.environ,
            "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-           "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": str(ROOT / "src")}
     modes = {}
     with tempfile.TemporaryDirectory() as td:
         for mode, spatial in (("spatial", True), ("timemux", False)):
-            cc = str(Path(td) / f"cc_{mode}")
+            # each mode's runs share one persistent compilation cache
+            cc_env = {**env, "JAX_COMPILATION_CACHE_DIR":
+                      str(Path(td) / f"cc_{mode}")}
             recs = []
             for run in ["cold"] + [f"warm{i}" for i in range(reps)]:
                 jpath = str(Path(td) / f"{mode}_{run}.json")
                 subprocess.run(
-                    _cluster_cmd(iters, jpath, cc, spatial), env=env,
+                    _cluster_cmd(iters, jpath, spatial), env=cc_env,
                     check=True, capture_output=True, timeout=900)
                 if run != "cold":       # cold run only primes the cc cache
                     recs.append(json.loads(Path(jpath).read_text()))
@@ -189,5 +194,10 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    import jax
+    if jax.default_backend() != "cpu":
+        sys.exit(f"bench_spatial.py: a CPU count tool, but JAX chose the "
+                 f"{jax.default_backend()!r} backend; run it with "
+                 f"JAX_PLATFORMS=cpu")
     for name, us, derived in run(quick=not args.full):
         print(f"{name},{us:.1f},{derived}")

@@ -15,6 +15,10 @@ per device) — the 1F1B-vs-GPipe memory gap in numbers.  The pipeline
 rows run in a child process because the stage mesh needs
 ``--xla_force_host_platform_device_count`` set before jax initializes.
 
+Every number here is a CPU count or a CPU timing, never a chip
+measurement: the script exits non-zero when JAX picks any backend but
+the CPU, and its children inherit the parent's platform.
+
 A third row set (``--tensor-parallel``, default 2) prices the 3-D
 layouts on a ``(stage, model)`` mesh: replicated compute vs
 tensor-sharded stages vs tensor + sequence-parallel, per snapped depth,
@@ -198,7 +202,6 @@ def bench_3d(arch: str, batch: int, seq: int, k: int, reps: int,
 def _spawn_pipeline_child(args) -> dict:
     env = dict(os.environ)
     env["SPB_BENCH_FORCE_DEVICES"] = str(args.pipeline_stages)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, __file__, "--_pipeline-child",
            "--arch", args.arch, "--batch", str(args.batch),
            "--seq", str(args.seq), "--k", str(args.k),
@@ -217,7 +220,6 @@ def _spawn_3d_child(args) -> dict:
     env = dict(os.environ)
     env["SPB_BENCH_FORCE_DEVICES"] = str(
         args.pipeline_stages * args.tensor_parallel)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, __file__, "--_3d-child",
            "--arch", args.arch, "--batch", str(args.batch),
            "--seq", str(args.seq), "--k", str(args.k),
@@ -252,6 +254,10 @@ def main():
                     help=argparse.SUPPRESS)
     ap.add_argument("--out", default=str(OUT))
     args = ap.parse_args()
+    if jax.default_backend() != "cpu":
+        sys.exit(f"bench_spb_step.py: a CPU count tool, but JAX chose the "
+                 f"{jax.default_backend()!r} backend; run it with "
+                 f"JAX_PLATFORMS=cpu")
 
     if getattr(args, "_pipeline_child"):
         rec = bench_pipeline(args.arch, args.batch, args.seq, args.k,

@@ -12,8 +12,8 @@ from typing import List
 
 import jax
 
-from repro.analysis.roofline import (HBM_BW, LINK_BW, PEAK_FLOPS,
-                                     count_params, load_record)
+from repro.analysis.roofline import (DRYRUN_DEVICE_KIND, count_params,
+                                     load_record, peaks)
 from repro.configs import get_config, list_archs, make_batch, reduced_config
 from repro.models import lm
 
@@ -31,9 +31,10 @@ def compiled_profiles() -> List[dict]:
             "grad_gb": round(n["nonembed"] * 2 / 2 ** 30, 2),   # bf16
         }
         if rec:
-            step = max(rec["flops_per_device"] / PEAK_FLOPS,
-                       rec["bytes_per_device"] / HBM_BW,
-                       rec["collective_bytes_per_device"] / LINK_BW)
+            pk = peaks(DRYRUN_DEVICE_KIND)
+            step = max(rec["flops_per_device"] / pk.flops,
+                       rec["bytes_per_device"] / pk.hbm_bw,
+                       rec["collective_bytes_per_device"] / pk.link_bw)
             # fwd ~ 1/3 of a full train step (fwd:bwd ~ 1:2)
             row.update({
                 "est_step_s": round(step, 3),

@@ -49,8 +49,8 @@ def main() -> None:
 
     # roofline summary (from dry-run cache)
     try:
-        from repro.analysis.roofline import full_table
-        for r in full_table():
+        from repro.analysis.roofline import DRYRUN_DEVICE_KIND, full_table
+        for r in full_table(DRYRUN_DEVICE_KIND):
             print(f"roofline/{r.arch}/{r.shape},0.0,"
                   f"compute={r.compute_s:.4f}s memory={r.memory_s:.4f}s "
                   f"collective={r.collective_s:.4f}s bound={r.dominant} "

@@ -6,6 +6,7 @@ a Philly-like trace and print the summary table.
 import argparse
 import statistics
 
+from repro.analysis.roofline import DRYRUN_DEVICE_KIND
 from repro.jigsaw.costmodel import profile_db
 from repro.jigsaw.schedulers import ALL_SCHEDULERS
 from repro.jigsaw.simulator import simulate
@@ -22,7 +23,7 @@ def main():
                     help="use the dry-run-derived TPU arch profiles")
     args = ap.parse_args()
 
-    db = profile_db(use_hlo=args.hlo_profiles)
+    db = profile_db(DRYRUN_DEVICE_KIND if args.hlo_profiles else None)
     kw = dict(num_jobs=args.jobs, seed=args.seed, db=db,
               mean_arrival_s=args.arrival, min_iters=100, max_iters=500)
     jobs_spb = generate_trace(spb=True, **kw)

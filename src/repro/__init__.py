@@ -1,4 +1,1 @@
 """SPB/Jigsaw reproduction framework (see README.md for the module map)."""
-from repro._jaxcompat import install as _install_jax_compat
-
-_install_jax_compat()

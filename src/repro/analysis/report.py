@@ -11,13 +11,13 @@ import json
 import sys
 from pathlib import Path
 
-from repro.analysis.roofline import (RESULTS, full_table, load_record,
-                                     roofline_row)
+from repro.analysis.roofline import (DRYRUN_DEVICE_KIND, RESULTS,
+                                     full_table, load_record, roofline_row)
 from repro.configs import get_config
 
 
 def md_roofline(mesh: str = "pod16x16") -> str:
-    rows = full_table(mesh)
+    rows = full_table(DRYRUN_DEVICE_KIND, mesh)
     out = ["| arch | shape | chips | compute (s) | memory (s) | collective (s) "
            "| bound | MFU | useful ratio | what moves the bound |",
            "|---|---|---:|---:|---:|---:|---|---:|---:|---|"]

@@ -1,8 +1,12 @@
 """Three-term roofline from the dry-run records + analytic MODEL_FLOPS.
 
-  compute    = flops_per_device / PEAK_FLOPS
-  memory     = bytes_per_device / HBM_BW
-  collective = wire_bytes_per_device / LINK_BW
+  compute    = flops_per_device / peak FLOP/s
+  memory     = bytes_per_device / peak HBM bytes/s
+  collective = wire_bytes_per_device / peak bytes/s of one ICI link
+
+The peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``;
+every caller names the kind it prices, and a kind not in the table is
+an error, not a default.
 
 flops/bytes come from the HLO-text cost model (analysis/hlo.py — XLA's
 cost_analysis ignores scan trip counts, see that module).  MODEL_FLOPS is
@@ -22,11 +26,37 @@ import jax
 
 from repro.config import ModelConfig, SHAPES, ShapeConfig, layer_kinds
 
-PEAK_FLOPS = 197e12          # bf16 / chip (v5e-class)
-HBM_BW = 819e9               # B/s / chip
-LINK_BW = 50e9               # B/s / link (ICI)
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published peak rates of one chip."""
+    flops: float        # bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    link_bw: float      # bytes/s of one chip-to-chip (ICI) link
+
+
+#: Keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, and
+#: 1,600 Gbit/s of interchip interconnect per chip over four links.
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; raises for a kind with
+    none on record."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peak rates for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 RESULTS = Path(__file__).resolve().parents[3] / "results" / "dryrun"
+#: The dry-run (``launch/dryrun.py``) lowers pod meshes of this chip; its
+#: records under :data:`RESULTS` are priced as such.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
 
 
 # ---------------------------------------------------------------------------
@@ -152,7 +182,7 @@ def decode_kv_bytes(cfg: ModelConfig, context: int) -> float:
 
 
 def decode_bandwidth_bound(cfg: ModelConfig, batch: int, context: int, *,
-                           bw: float = HBM_BW) -> float:
+                           bw: float) -> float:
     """Bandwidth-bound decode throughput ceiling in tokens/s.
 
     Each decode step streams the (active) weights once — amortized over
@@ -343,18 +373,20 @@ def load_record(arch: str, shape: str, mesh: str = "pod16x16",
     return rec if rec.get("ok") else None
 
 
-def roofline_row(rec: dict, cfg: ModelConfig) -> RooflineRow:
+def roofline_row(rec: dict, cfg: ModelConfig,
+                 device_kind: str) -> RooflineRow:
     shape = SHAPES[rec["shape"]]
     chips = rec["chips"]
-    comp = rec["flops_per_device"] / PEAK_FLOPS
-    mem = rec["bytes_per_device"] / HBM_BW
-    coll = rec["collective_bytes_per_device"] / LINK_BW
+    pk = peaks(device_kind)
+    comp = rec["flops_per_device"] / pk.flops
+    mem = rec["bytes_per_device"] / pk.hbm_bw
+    coll = rec["collective_bytes_per_device"] / pk.link_bw
     terms = {"compute": comp, "memory": mem, "collective": coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops(cfg, shape)
     hlo_global = rec["flops_per_device"] * chips
     step = max(terms.values())
-    mfu = mf / (chips * PEAK_FLOPS * step) if step > 0 else 0.0
+    mfu = mf / (chips * pk.flops * step) if step > 0 else 0.0
     temp = rec.get("memory_analysis", {}).get("temp_size_in_bytes", 0) / 2 ** 30
     return RooflineRow(
         arch=rec["arch"], shape=rec["shape"], mesh=rec["mesh"], chips=chips,
@@ -364,13 +396,14 @@ def roofline_row(rec: dict, cfg: ModelConfig) -> RooflineRow:
         step_s=step, mfu=mfu, temp_gib=temp)
 
 
-def full_table(mesh: str = "pod16x16") -> List[RooflineRow]:
+def full_table(device_kind: str, mesh: str = "pod16x16"
+               ) -> List[RooflineRow]:
     from repro.configs import cells, get_config
     rows = []
     for arch, shape, skip in cells():
         rec = load_record(arch, shape, mesh)
         if rec:
-            rows.append(roofline_row(rec, get_config(arch)))
+            rows.append(roofline_row(rec, get_config(arch), device_kind))
     return rows
 
 
@@ -388,4 +421,4 @@ def format_table(rows: List[RooflineRow]) -> str:
 
 
 if __name__ == "__main__":
-    print(format_table(full_table()))
+    print(format_table(full_table(DRYRUN_DEVICE_KIND)))

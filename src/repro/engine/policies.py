@@ -199,8 +199,9 @@ def make_policy(name: str, cfg: ModelConfig, spb: SPBConfig, *,
         return CyclePolicy(cfg, spb)
     if name == "costmodel":
         if profile is None:
+            from repro.analysis.roofline import DRYRUN_DEVICE_KIND
             from repro.jigsaw.costmodel import profile_db
-            db = profile_db()
+            db = profile_db(DRYRUN_DEVICE_KIND)
             profile = db.get(cfg.name)
             if profile is None:
                 # no HLO-derived profile for this arch (run the dry-run to

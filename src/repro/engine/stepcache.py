@@ -39,13 +39,14 @@ True
 >>> stepcache.GLOBAL.stats()["hits"]
 1
 
-This module also wires jax's *persistent* compilation cache (the
-on-disk XLA-level cache behind ``--compilation-cache-dir``), which
+This module also turns on jax's *persistent* compilation cache (the
+on-disk XLA-level cache, :func:`enable_compilation_cache`), which
 dedupes compiles across *processes* the way :data:`GLOBAL` dedupes
 traces within one.
 """
 from __future__ import annotations
 
+import os
 import threading
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -113,36 +114,29 @@ def mesh_fingerprint(mesh) -> Tuple:
 
 # -- jax persistent compilation cache (cross-process) ----------------------
 
-def enable_persistent_compilation_cache(cache_dir) -> int:
-    """Point jax's on-disk XLA compilation cache at ``cache_dir`` (created
-    if needed) with thresholds dropped so every compile is eligible.
-    Returns the number of entries already present, for
-    :func:`persistent_cache_report`."""
+#: Where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` is not set: one
+#: fixed directory at the root of the checkout (listed in .gitignore).  A
+#: fixed path, so that a later process finds what an earlier one compiled.
+DEFAULT_COMPILATION_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compilation_cache() -> Path:
+    """Turn on jax's on-disk XLA compilation cache, placed from outside.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, jax already reads that
+    directory and no other is set here; without it the cache goes to
+    :data:`DEFAULT_COMPILATION_CACHE`.  The size and compile-time
+    thresholds are dropped so that every compile is eligible.  Returns the
+    directory in use.  Entry points call this first; importing this
+    module changes nothing.
+    """
     import jax
-    path = Path(cache_dir)
-    path.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):
-            pass                    # knob absent on this jax version
-    return _cache_entries(path)
-
-
-def _cache_entries(path: Path) -> int:
-    try:
-        return sum(1 for p in Path(path).iterdir() if p.is_file())
-    except OSError:
-        return 0
-
-
-def persistent_cache_report(cache_dir, entries_before: int) -> str:
-    """The one-line hit/miss log for ``--compilation-cache-dir``."""
-    now = _cache_entries(Path(cache_dir))
-    new = max(0, now - entries_before)
-    verdict = ("miss" if new else
-               "hit — all compiles served from cache")
-    return (f"[cc] persistent compilation cache {cache_dir}: "
-            f"{new} new entries ({verdict}), {now} total")
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        path = Path(env)
+    else:
+        path = DEFAULT_COMPILATION_CACHE
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

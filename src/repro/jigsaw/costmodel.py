@@ -35,11 +35,6 @@ V100_PROFILES = {
     "googlenet": (41.33, 0.05, 99.17, 5.96, 24),
 }
 
-# Hardware constants (TPU v5e-class) for HLO-derived profiles
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
-
 
 @dataclass
 class ModelProfile:
@@ -74,9 +69,12 @@ def v100_profiles() -> Dict[str, ModelProfile]:
     return out
 
 
-def hlo_profiles(results_dir: Optional[Path] = None,
+def hlo_profiles(device_kind: str, results_dir: Optional[Path] = None,
                  shape: str = "train_4k") -> Dict[str, ModelProfile]:
-    """Per-arch profiles from the dry-run JSONs (per-device roofline)."""
+    """Per-arch profiles from the dry-run JSONs (per-device roofline),
+    priced at the published peaks of ``device_kind``."""
+    from repro.analysis.roofline import peaks
+    pk = peaks(device_kind)
     if results_dir is None:
         results_dir = Path(__file__).resolve().parents[3] / "results" / "dryrun"
     out = {}
@@ -89,7 +87,7 @@ def hlo_profiles(results_dir: Optional[Path] = None,
         flops = rec["flops_per_device"]
         byts = rec["bytes_per_device"]
         coll = rec["collective_bytes_per_device"]
-        step = max(flops / PEAK_FLOPS, byts / HBM_BW, coll / LINK_BW)
+        step = max(flops / pk.flops, byts / pk.hbm_bw, coll / pk.link_bw)
         ma = rec.get("memory_analysis", {})
         temp = ma.get("temp_size_in_bytes", 8 * 2 ** 30) / 2 ** 30
         args = ma.get("argument_size_in_bytes", 4 * 2 ** 30) / 2 ** 30
@@ -101,10 +99,13 @@ def hlo_profiles(results_dir: Optional[Path] = None,
     return out
 
 
-def profile_db(use_hlo: bool = True) -> Dict[str, ModelProfile]:
+def profile_db(hlo_device_kind: Optional[str] = None
+               ) -> Dict[str, ModelProfile]:
+    """The paper's V100 profiles, plus the dry-run-derived ones priced for
+    ``hlo_device_kind`` when a kind is named."""
     db = v100_profiles()
-    if use_hlo:
-        db.update(hlo_profiles())
+    if hlo_device_kind is not None:
+        db.update(hlo_profiles(hlo_device_kind))
     return db
 
 

@@ -99,14 +99,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+            lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
 def fwd_kernel_layout(qt, kt, vt, *, causal: bool = True, window: int = 0,
                       q_block: int = 128, kv_block: int = 128,
                       with_lse: bool = False, interpret: bool = False):
     """Launch the forward in kernel layout.  qt: (B, H, Sq, D); kt, vt:
-    (B, K, Sk, D).  Returns ot, or (ot, lse) when ``with_lse``."""
+    (B, K, Sk, D).  Returns ot, or (ot, lse) when ``with_lse``; lse is
+    (B, H, Sq, 1) f32 — the trailing unit lane dim makes its blocks
+    (q_block, 1), which Mosaic's (8, 128) block rule accepts."""
     B, H, Sq, D = qt.shape
     K, Sk = kt.shape[1], kt.shape[2]
     G = H // K
@@ -124,9 +126,9 @@ def fwd_kernel_layout(qt, kt, vt, *, causal: bool = True, window: int = 0,
                               lambda b, h, i, j: (b, h, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((B, H, Sq, D), qt.dtype)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((1, 1, q_block),
-                                      lambda b, h, i, j: (b, h, i)))
-        out_shape.append(jax.ShapeDtypeStruct((B, H, Sq), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, q_block, 1),
+                                      lambda b, h, i, j: (b, h, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32))
 
     result = pl.pallas_call(
         kernel,

@@ -47,7 +47,7 @@ def fwd_res_kernel_layout(qt, kt, vt, *, causal: bool = True,
                           window: int = 0, q_block: int = 128,
                           kv_block: int = 128, interpret: bool = False):
     """Forward in kernel layout.  qt: (B, H, Sq, D); kt, vt: (B, K, Sk, D).
-    Returns (ot, lse) with ot: (B, H, Sq, D), lse: (B, H, Sq) f32."""
+    Returns (ot, lse) with ot: (B, H, Sq, D), lse: (B, H, Sq, 1) f32."""
     return fwd_kernel_layout(qt, kt, vt, causal=causal, window=window,
                              q_block=q_block, kv_block=kv_block,
                              with_lse=True, interpret=interpret)
@@ -71,7 +71,7 @@ def flash_attention_fwd_res(q, k, v, *, causal: bool = True, window: int = 0,
 def _delta_kernel(o_ref, do_ref, delta_ref):
     o = o_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    delta_ref[0, 0] = jnp.sum(o * do, axis=1)
+    delta_ref[0, 0] = jnp.sum(o * do, axis=1, keepdims=True)
 
 
 def _compute_delta(ot, dot_, q_block, interpret):
@@ -84,8 +84,9 @@ def _compute_delta(ot, dot_, q_block, interpret):
             pl.BlockSpec((1, 1, q_block, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, q_block, D), lambda b, h, i: (b, h, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, q_block), lambda b, h, i: (b, h, i)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, q_block, 1),
+                               lambda b, h, i: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
@@ -121,10 +122,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         mask = pair_mask(s.shape, q_start, k_start, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dq_scr[...] += lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
 
@@ -165,13 +166,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         mask = pair_mask(s.shape, q_start, k_start, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         # dv += P^T dO
         dv_scr[...] += lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         # dk += dS^T Q
         dk_scr[...] += lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
@@ -190,7 +191,7 @@ def bwd_kernel_layout(qt, kt, vt, ot, lse, dot_, *, causal: bool = True,
                       window: int = 0, q_block: int = 128,
                       kv_block: int = 128, interpret: bool = False):
     """Backward in kernel layout: all operands (B, H|K, S, D), lse
-    (B, H, Sq) f32.  Returns (dqt, dkt, dvt) in the same layout."""
+    (B, H, Sq, 1) f32.  Returns (dqt, dkt, dvt) in the same layout."""
     B, H, Sq, D = qt.shape
     K, Sk = kt.shape[1], kt.shape[2]
     G = H // K
@@ -213,8 +214,8 @@ def bwd_kernel_layout(qt, kt, vt, ot, lse, dot_, *, causal: bool = True,
             pl.BlockSpec((1, 1, kv_block, D), lambda b, h, i, j: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, kv_block, D), lambda b, h, i, j: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, q_block, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, q_block), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, q_block), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, q_block, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, q_block, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, q_block, D),
                                lambda b, h, i, j: (b, h, i, 0)),
@@ -241,10 +242,10 @@ def bwd_kernel_layout(qt, kt, vt, ot, lse, dot_, *, causal: bool = True,
                          lambda b, kh, j, g, i: (b, kh, j, 0)),
             pl.BlockSpec((1, 1, q_block, D),
                          lambda b, kh, j, g, i: (b, kh * G + g, i, 0)),
-            pl.BlockSpec((1, 1, q_block),
-                         lambda b, kh, j, g, i: (b, kh * G + g, i)),
-            pl.BlockSpec((1, 1, q_block),
-                         lambda b, kh, j, g, i: (b, kh * G + g, i)),
+            pl.BlockSpec((1, 1, q_block, 1),
+                         lambda b, kh, j, g, i: (b, kh * G + g, i, 0)),
+            pl.BlockSpec((1, 1, q_block, 1),
+                         lambda b, kh, j, g, i: (b, kh * G + g, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, kv_block, D),

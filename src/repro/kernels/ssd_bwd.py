@@ -2,9 +2,10 @@
 
 FlashAttention-2 style split (mirrors ``flash_attention_bwd.py``):
 
-- ``fwd_res_kernel_layout`` re-runs the forward scan but additionally
-  records the (P, N) state *entering* each chunk.  Those per-chunk states
-  are the only residuals the backward needs beyond the inputs themselves —
+- ``fwd_res_kernel_layout`` runs the forward kernel with ``with_states``,
+  which also records the (P, N) state *entering* each chunk.  Those
+  per-chunk states are the only residuals the backward needs beyond the
+  inputs themselves —
   O(S/Q · P · N) extra memory instead of re-materializing the full
   sequential recurrence.
 - ``bwd_kernel_layout`` walks the chunks in **reverse** grid order,
@@ -33,6 +34,8 @@ Backward per chunk, given (dy, dS_out):
           - dd * d,  dd = rowsum(b * (x @ dS_out)) (from d)
     dcsum[-1] += alpha * sum(dS_out * S_in) + sum(dd * d)
     ddA = reverse-cumsum(dcsum)   (csum resets per chunk)
+
+The cumulative sums use ``ssd.chunk_cumsum`` (Mosaic lowers no cumsum).
 """
 from __future__ import annotations
 
@@ -44,91 +47,27 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _fwd_res_kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, state_out_ref,
-                    chunk_states_ref, state_scr, *, chunk: int, nc: int):
-    """Forward scan that also records the state entering each chunk."""
-    ic = pl.program_id(1)
-
-    @pl.when(ic == 0)
-    def _init():
-        state_scr[...] = jnp.zeros_like(state_scr)
-
-    # residual: the (P, N) state *entering* this chunk
-    chunk_states_ref[0, 0] = state_scr[...]
-
-    xdt = xdt_ref[0].astype(jnp.float32)            # (Q, P)
-    dA = dA_ref[0].astype(jnp.float32)              # (Q, 1)
-    b = b_ref[0].astype(jnp.float32)                # (Q, N)
-    c = c_ref[0].astype(jnp.float32)                # (Q, N)
-
-    csum = jnp.cumsum(dA[:, 0])
-    diff = csum[:, None] - csum[None, :]
-    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(row >= col, jnp.exp(diff), 0.0)
-    scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(scores * L, xdt, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    state = state_scr[...]
-    y = y + jnp.exp(csum)[:, None] * jax.lax.dot_general(
-        c, state, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    y_ref[0] = y.astype(y_ref.dtype)
-    decay = jnp.exp(csum[-1] - csum)
-    upd = jax.lax.dot_general(xdt, b * decay[:, None],
-                              (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    state_scr[...] = state * jnp.exp(csum[-1]) + upd
-
-    @pl.when(ic == nc - 1)
-    def _emit_state():
-        state_out_ref[0] = state_scr[...]
+from repro.kernels.ssd import chunk_cumsum, ssd_fwd_kernel_layout
 
 
 def fwd_res_kernel_layout(xr, dr, br, cr, *, chunk: int,
                           interpret: bool = False):
-    """Forward + residuals on kernel-native layouts.
+    """Forward + residuals on kernel-native layouts — the SAME kernel as
+    the primal forward (``ssd._ssd_kernel``), launched with
+    ``with_states=True``.
 
     xr: (B*H, S, P); dr: (B*H, S, 1); br, cr: (B*H, S, N).
     Returns (y (B*H,S,P) f32, state (B*H,P,N) f32,
              chunk_states (B*H, nc, P, N) f32).
     """
-    BH, S, P = xr.shape
-    N = br.shape[-1]
-    assert S % chunk == 0
-    nc = S // chunk
-
-    kernel = functools.partial(_fwd_res_kernel, chunk=chunk, nc=nc)
-    return pl.pallas_call(
-        kernel,
-        grid=(BH, nc),
-        in_specs=[
-            pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, P, N), lambda b, c: (b, 0, 0)),
-            pl.BlockSpec((1, 1, P, N), lambda b, c: (b, c, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, P), jnp.float32),
-            jax.ShapeDtypeStruct((BH, P, N), jnp.float32),
-            jax.ShapeDtypeStruct((BH, nc, P, N), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(xr, dr, br, cr)
+    return ssd_fwd_kernel_layout(xr, dr, br, cr, chunk=chunk,
+                                 with_states=True, interpret=interpret)
 
 
 def _bwd_kernel(xdt_ref, dA_ref, b_ref, c_ref, sin_ref, dy_ref, dstate_ref,
                 dx_ref, ddA_ref, db_ref, dc_ref, ds_scr, *, chunk: int):
-    """One reverse chunk step; ``ds_scr`` carries the state adjoint."""
+    """One reverse chunk step; ``ds_scr`` carries the state adjoint.
+    Per-timestep vectors are (Q, 1) columns or (1, Q) rows, never 1-D."""
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
@@ -143,15 +82,14 @@ def _bwd_kernel(xdt_ref, dA_ref, b_ref, c_ref, sin_ref, dy_ref, dstate_ref,
     dy = dy_ref[0].astype(jnp.float32)              # (Q, P)
     ds_out = ds_scr[...]                            # (P, N)
 
-    csum = jnp.cumsum(dA[:, 0])                     # (Q,)
-    e = jnp.exp(csum)
-    alpha = e[-1]
-    d = jnp.exp(csum[-1] - csum)
-    diff = csum[:, None] - csum[None, :]
+    csum, csum_row = chunk_cumsum(dA)               # (Q, 1), (1, Q)
+    total = jnp.sum(dA, axis=0, keepdims=True)      # (1, 1) = csum[-1]
+    e = jnp.exp(csum)                               # (Q, 1)
+    alpha = jnp.exp(total)                          # (1, 1)
+    d = jnp.exp(total - csum)                       # (Q, 1)
     row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tri = row >= col
-    L = jnp.where(tri, jnp.exp(diff), 0.0)
+    L = jnp.where(row >= col, jnp.exp(csum - csum_row), 0.0)
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     G = scores * L                                  # (Q, Q), masked
@@ -164,8 +102,7 @@ def _bwd_kernel(xdt_ref, dA_ref, b_ref, c_ref, sin_ref, dy_ref, dstate_ref,
     b_dsT = jax.lax.dot_general(b, ds_out, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (Q, P)
     dx = jax.lax.dot_general(G, dy, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32) \
-        + d[:, None] * b_dsT
+                             preferred_element_type=jnp.float32) + d * b_dsT
 
     # dG = dy @ x^T; dscores = dG * L (mask folds into L)
     dG = jax.lax.dot_general(dy, x, (((1,), (1,)), ((), ())),
@@ -173,34 +110,37 @@ def _bwd_kernel(xdt_ref, dA_ref, b_ref, c_ref, sin_ref, dy_ref, dstate_ref,
     M = dG * L
     dc = jax.lax.dot_general(M, b, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32) \
-        + e[:, None] * jax.lax.dot_general(
-            dy, s_in, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        + e * jax.lax.dot_general(dy, s_in, (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
     db = jax.lax.dot_general(M, c, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32) \
-        + d[:, None] * x_ds
+                             preferred_element_type=jnp.float32) + d * x_ds
 
-    # dcsum: decay-matrix term, inter-chunk e term, state-update d term
+    # dcsum: decay-matrix term (rowsum - colsum; the colsum is a (1, Q)
+    # row, turned into a column by a diagonal mask), inter-chunk e term,
+    # state-update d term
     T = dG * G
-    dcsum = T.sum(axis=1) - T.sum(axis=0)
-    dcsum = dcsum + e * (dy * inter).sum(axis=1)
-    dd = (b * x_ds).sum(axis=1)                     # (Q,)
+    colsum = jnp.sum(jnp.where(row == col, jnp.sum(T, axis=0, keepdims=True),
+                               0.0), axis=1, keepdims=True)
+    dcsum = jnp.sum(T, axis=1, keepdims=True) - colsum
+    dcsum = dcsum + e * jnp.sum(dy * inter, axis=1, keepdims=True)
+    dd = jnp.sum(b * x_ds, axis=1, keepdims=True)   # (Q, 1)
     s_term = dd * d
     dcsum = dcsum - s_term
-    last_extra = alpha * (ds_out * s_in).sum() + s_term.sum()
-    idx = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)[:, 0]
+    last_extra = alpha * jnp.sum(jnp.sum(ds_out * s_in, axis=1, keepdims=True),
+                                 axis=0, keepdims=True) \
+        + jnp.sum(s_term, axis=0, keepdims=True)    # (1, 1)
+    idx = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
     dcsum = jnp.where(idx == chunk - 1, dcsum + last_extra, dcsum)
-    # csum resets each chunk: ddA_t = sum_{u >= t} dcsum_u (reverse cumsum,
-    # written flip-free as total - prefix + self)
-    ddA = dcsum.sum() - jnp.cumsum(dcsum) + dcsum
+    # csum resets each chunk: ddA_t = sum_{u >= t} dcsum_u
+    ddA, _ = chunk_cumsum(dcsum, reverse=True)
 
     # carry: adjoint of the state entering this chunk
     ds_scr[...] = alpha * ds_out + jax.lax.dot_general(
-        dy * e[:, None], c, (((0,), (0,)), ((), ())),
+        dy * e, c, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     dx_ref[0] = dx
-    ddA_ref[0] = ddA[:, None]
+    ddA_ref[0] = ddA
     db_ref[0] = db
     dc_ref[0] = dc
 

@@ -124,9 +124,6 @@ def main(argv=None):
                     help="HFTA-style horizontal fusion: same-shaped jobs "
                          "stack into one vmapped train step scheduled as "
                          "the group leader")
-    ap.add_argument("--compilation-cache-dir", default="",
-                    help="jax persistent compilation cache directory "
-                         "(XLA executables persist across processes)")
     ap.add_argument("--aot-cache", default="")
     ap.add_argument("--fault-plan", default="",
                     help="inject faults, ';'-separated (virtual seconds): "
@@ -156,10 +153,6 @@ def main(argv=None):
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
 
-    cc_before = None
-    if args.compilation_cache_dir:
-        cc_before = stepcache.enable_persistent_compilation_cache(
-            args.compilation_cache_dir)
     runtime, backend = build_session(args)
     t0 = time.time()
     res = runtime.run()
@@ -196,9 +189,6 @@ def main(argv=None):
               f"max_concurrent={backend.max_concurrent_tasks} "
               f"resizes={sum(backend.resizes.values())} "
               f"fused_groups={len(backend.fused)}", flush=True)
-    if cc_before is not None:
-        print(stepcache.persistent_cache_report(
-            args.compilation_cache_dir, cc_before), flush=True)
     if res.crashes or res.task_retries or res.failed_jobs:
         print(f"[cluster] faults: crashes={res.crashes} "
               f"retries={res.task_retries} "
@@ -239,4 +229,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    stepcache.enable_compilation_cache()
     main()

@@ -58,16 +58,8 @@ def serve(argv=None):
     ap.add_argument("--aot-cache", default=None,
                     help="AOT table root: import the serve executables "
                          "if present, else compile and export them")
-    ap.add_argument("--compilation-cache-dir", default="",
-                    help="jax persistent compilation cache directory "
-                         "(XLA executables persist across processes)")
     args = ap.parse_args(argv)
 
-    cc_before = None
-    if args.compilation_cache_dir:
-        from repro.engine import stepcache
-        cc_before = stepcache.enable_persistent_compilation_cache(
-            args.compilation_cache_dir)
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     geom = default_geometry(num_slots=args.slots, page_size=args.page_size,
                             max_context=args.max_context)
@@ -117,12 +109,10 @@ def serve(argv=None):
     print(f"[serve] slots_reused={st['slots_reused']} "
           f"slot_uses={st['slot_uses']} pages_alloc={st['page_allocs']} "
           f"pages_freed={st['page_frees']} free_pages={st['free_pages']}")
-    if cc_before is not None:
-        from repro.engine import stepcache
-        print(stepcache.persistent_cache_report(
-            args.compilation_cache_dir, cc_before))
     return done
 
 
 if __name__ == "__main__":
+    from repro.engine.stepcache import enable_compilation_cache
+    enable_compilation_cache()
     serve()

@@ -102,10 +102,6 @@ def train(argv=None):
                          "the dry-run writes); a process with matching "
                          "config + mesh topology reuses the table with no "
                          "re-trace/re-compile")
-    ap.add_argument("--compilation-cache-dir", default="",
-                    help="jax persistent compilation cache directory: "
-                         "XLA compiles persist across processes (on top "
-                         "of the AOT step-table cache)")
     ap.add_argument("--compression", default="none")
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--checkpoint-every", type=int, default=20)
@@ -120,11 +116,6 @@ def train(argv=None):
                          "Pallas kernels (interpret mode on CPU)")
     args = ap.parse_args(argv)
 
-    cc_before = None
-    if args.compilation_cache_dir:
-        from repro.engine import stepcache
-        cc_before = stepcache.enable_persistent_compilation_cache(
-            args.compilation_cache_dir)
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if args.use_pallas:
         import dataclasses as _dc
@@ -160,10 +151,6 @@ def train(argv=None):
             args.resume = True
     if mgr:
         mgr.wait()
-    if cc_before is not None:
-        from repro.engine import stepcache
-        print(stepcache.persistent_cache_report(
-            args.compilation_cache_dir, cc_before), flush=True)
     return history
 
 
@@ -217,4 +204,6 @@ def _run(cfg, tcfg, spb_cfg, mesh, args, mgr, history):
 
 
 if __name__ == "__main__":
+    from repro.engine.stepcache import enable_compilation_cache
+    enable_compilation_cache()
     train()

@@ -402,20 +402,23 @@ def init_attention(key, cfg: ModelConfig, dtype) -> Params:
 
 
 def _pallas_attention(q: Array, k: Array, v: Array, *, causal: bool,
-                      window: int) -> Optional[Array]:
-    """Dispatch to the differentiable Pallas flash-attention kernel when
-    the shapes tile cleanly; None means fall back to the pure-jnp
-    blockwise path.  On TPU the blocks must respect Mosaic's native
-    tiling (sublane multiple of 8, lane dim 128), so short or ragged
-    sequences and narrow heads fall back rather than feeding the MXU
-    unaligned tiles."""
+                      window: int) -> Array:
+    """The differentiable Pallas flash-attention kernel.  A shape it
+    cannot tile raises, naming the shape: it never switches to the
+    blockwise path behind the caller's back.  On TPU the blocks must also
+    respect Mosaic's native tiling (sublane multiple of 8, lane dim 128)."""
     Sq, Sk = q.shape[1], k.shape[1]
     D = q.shape[-1]
     qb, kb = min(128, Sq), min(128, Sk)
-    if Sq % qb or Sk % kb or q.shape[2] % k.shape[2]:
-        return None
-    if jax.default_backend() == "tpu" and (qb % 8 or kb % 8 or D % 128):
-        return None
+    on_tpu = jax.default_backend() == "tpu"
+    if (Sq % qb or Sk % kb or q.shape[2] % k.shape[2]
+            or (on_tpu and (qb % 8 or kb % 8 or D % 128))):
+        raise ValueError(
+            f"use_pallas: flash attention cannot tile q {q.shape} / kv "
+            f"{k.shape} (B, S, heads, head_dim) with blocks ({qb}, {kb}): "
+            f"sequence lengths must be whole blocks and q heads a multiple "
+            f"of kv heads; on TPU blocks must be multiples of 8 and "
+            f"head_dim a multiple of 128")
     from repro.kernels.ops import flash_attention
     return flash_attention(q, k, v, causal=causal, window=window,
                            q_block=qb, kv_block=kb)
@@ -444,10 +447,9 @@ def attention_fwd(p: Params, x: Array, cfg: ModelConfig, *, kind: str,
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     window = cfg.window if kind == "local" else 0
-    o = None
     if cfg.use_pallas:
         o = _pallas_attention(q, k, v, causal=True, window=window)
-    if o is None:
+    else:
         o = blockwise_attention(q, k, v, causal=True, window=window,
                                 q_block=cfg.attn_q_block,
                                 kv_block=cfg.attn_kv_block)

@@ -134,28 +134,36 @@ def _ssd_scan(xh: Array, dA: Array, Bm: Array, Cm: Array, state0: Array,
 def _use_pallas_ssd(cfg: ModelConfig, S: int, P: int, N: int) -> bool:
     """Route the train/prefill scan through the Pallas SSD kernel?
 
-    Mirrors ``layers._pallas_attention``: opt-in via ``cfg.use_pallas``;
-    on TPU additionally require MXU-friendly tiling (interpret mode on
+    Mirrors ``layers._pallas_attention``: opt-in via ``cfg.use_pallas``.
+    On TPU a shape that misses MXU-friendly tiling raises, naming the
+    shape, instead of quietly taking the jnp scan (interpret mode on
     other backends handles any shape).
     """
     if not cfg.use_pallas:
         return False
-    if jax.default_backend() == "tpu":
-        Q = min(cfg.ssm.chunk, S)
-        return Q % 8 == 0 and P % 8 == 0 and N % 128 == 0
+    Q = min(cfg.ssm.chunk, S)
+    if jax.default_backend() == "tpu" and (Q % 8 or P % 8 or N % 128):
+        raise ValueError(
+            f"use_pallas: the SSD kernel cannot tile chunk={Q}, "
+            f"head_dim={P}, d_state={N} on TPU (needs chunk and head_dim "
+            f"multiples of 8, d_state a multiple of 128)")
     return True
 
 
 def _use_pallas_rglru(cfg: ModelConfig, S: int, W: int) -> bool:
+    """As :func:`_use_pallas_ssd`, for the RG-LRU scan kernel."""
     if not cfg.use_pallas:
         return False
-    if jax.default_backend() == "tpu":
-        Q = min(cfg.lru.block_width, S)
-        return Q % 8 == 0 and W % 128 == 0
+    Q = min(cfg.lru.block_width, S)
+    if jax.default_backend() == "tpu" and (Q % 8 or W % 128):
+        raise ValueError(
+            f"use_pallas: the RG-LRU kernel cannot tile block_width={Q}, "
+            f"lru_width={W} on TPU (needs a block_width multiple of 8 and "
+            f"an lru_width multiple of 128)")
     return True
 
 
-def mamba2_core(p: Params, x: Array, cfg: ModelConfig, state0=None):
+def mamba2_core(p: Params, x: Array, cfg: ModelConfig):
     """Shared train/prefill path.  x: (B,S,D) -> (y, final_state, conv_tail)."""
     s: SSMConfig = cfg.ssm
     B_, S, D = x.shape
@@ -173,14 +181,13 @@ def mamba2_core(p: Params, x: Array, cfg: ModelConfig, state0=None):
     A = -jnp.exp(p["A_log"])                                      # (H,)
     dA = dt * A
     # big tensors stay in the storage dtype (decays/state are f32 inside)
-    if state0 is None and _use_pallas_ssd(cfg, S, P, N):
+    if _use_pallas_ssd(cfg, S, P, N):
         from repro.kernels import ops as _K
         y, state = _K.ssd(xh * dt[..., None].astype(xh.dtype), dA,
                           Bm, Cm, chunk=s.chunk)
         y = y.astype(xh.dtype)
     else:
-        if state0 is None:
-            state0 = jnp.zeros((B_, H, P, N), jnp.float32)
+        state0 = jnp.zeros((B_, H, P, N), jnp.float32)
         y, state = _ssd_scan(xh * dt[..., None].astype(xh.dtype), dA,
                              Bm, Cm, state0, s.chunk)
     y = y + (p["D"].astype(xh.dtype)[None, None, :, None] * xh)
@@ -312,7 +319,7 @@ def _lru_scan(a: Array, b: Array, h0: Array, chunk: int):
     return ys, ys[:, -1] if pad else h
 
 
-def rglru_core(p: Params, x: Array, cfg: ModelConfig, h0=None):
+def rglru_core(p: Params, x: Array, cfg: ModelConfig):
     l: LRUConfig = cfg.lru
     B_, S, D = x.shape
     W = l.lru_width or D
@@ -321,13 +328,12 @@ def rglru_core(p: Params, x: Array, cfg: ModelConfig, h0=None):
     xc = jax.nn.silu(causal_conv(xb, p["conv_w"], p["conv_b"]))
     xf = xc.astype(jnp.float32)
     a, gated = _rglru_gates(p, xf)
-    if h0 is None and _use_pallas_rglru(cfg, S, W):
+    if _use_pallas_rglru(cfg, S, W):
         from repro.kernels import ops as _K
         h = _K.rglru(a, gated, chunk=l.block_width)
         hT = h[:, -1]
     else:
-        if h0 is None:
-            h0 = jnp.zeros((B_, W), jnp.float32)
+        h0 = jnp.zeros((B_, W), jnp.float32)
         h, hT = _lru_scan(a, gated, h0, l.block_width)
     y = (h.astype(x.dtype) * z) @ p["out_proj"]
     conv_tail = xb[:, -(l.d_conv - 1):]

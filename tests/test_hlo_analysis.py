@@ -110,3 +110,13 @@ def test_group_size_parsing():
     assert hlo._group_size("replica_groups=[16,16]<=[256]", 256) == 16
     assert hlo._group_size("replica_groups={{0,1,2,3}}", 256) == 4
     assert hlo._group_size("no groups here", 256) == 256
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """The roofline and the JigSaw cost model price a named chip from one
+    table; a kind with no published peaks is an error, not a default."""
+    from repro.analysis.roofline import peaks
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")

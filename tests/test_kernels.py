@@ -130,3 +130,37 @@ def test_model_lru_matches_kernel():
     y_kern = rglru_scan(a, b, chunk=32, width_block=32, interpret=True)
     np.testing.assert_allclose(np.asarray(y_model), np.asarray(y_kern),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_pallas_attention_refuses_untileable_sequence():
+    """With ``use_pallas`` a sequence that is not a whole number of kernel
+    blocks raises, naming the shape, instead of taking the blockwise
+    path."""
+    from repro.models.layers import _pallas_attention
+    q = jnp.zeros((1, 192, 4, 16))
+    kv = jnp.zeros((1, 192, 2, 16))
+    with pytest.raises(ValueError, match=r"\(1, 192, 4, 16\)"):
+        _pallas_attention(q, kv, kv, causal=True, window=0)
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "recurrentgemma-2b"])
+def test_pallas_scans_refuse_untileable_shapes_on_tpu(arch, monkeypatch):
+    """On TPU a scan width Mosaic cannot tile raises instead of quietly
+    running the jnp scan; the reduced widths (16 / 64) are such shapes.
+    The published widths pass the same check."""
+    import dataclasses
+
+    from repro.configs import get_config, reduced_config
+    from repro.models import ssm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def route(cfg):
+        cfg = dataclasses.replace(cfg, use_pallas=True)
+        if arch.startswith("mamba2"):
+            return ssm._use_pallas_ssd(cfg, 2048, cfg.ssm.head_dim,
+                                       cfg.ssm.d_state)
+        return ssm._use_pallas_rglru(cfg, 2048, cfg.lru.lru_width)
+
+    with pytest.raises(ValueError, match="use_pallas"):
+        route(reduced_config(arch))
+    assert route(get_config(arch))

@@ -120,7 +120,7 @@ def test_markov_is_learnable_structure():
     lm = MarkovLM(64, seed=0)
     toks = lm.sample(8, 512, step=0)
     # empirical conditional entropy under the true transition matrix
-    probs = lm._probs[toks[:, :-1], toks[:, 1:]]
+    probs = lm.transition_prob(toks[:, :-1], toks[:, 1:])
     ce = -np.log(probs + 1e-9).mean()
     assert ce < np.log(64) * 0.9
 

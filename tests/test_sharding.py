@@ -103,7 +103,7 @@ def test_zero1_composes_with_pipeline_state_pspec():
     cfg = reduced_config("yi-6b")
     tcfg = TrainConfig(optimizer="adamw")
     shapes = steps_lib.train_state_shapes(cfg, tcfg)
-    mesh = jax.sharding.AbstractMesh((("stage", 2), ("data", 2)))
+    mesh = jax.sharding.AbstractMesh((2, 2), ("stage", "data"))
     specs = shd.pipeline_state_pspec(shapes, mesh=mesh, zero1=True)
 
     # params: stage on the layer dim, never 'data'
@@ -136,7 +136,7 @@ def test_zero1_composes_with_pipeline_state_pspec():
 def test_pipeline_state_pspec_without_zero1_keeps_data_free():
     cfg = reduced_config("yi-6b")
     shapes = steps_lib.train_state_shapes(cfg, TrainConfig())
-    mesh = jax.sharding.AbstractMesh((("stage", 2), ("data", 2)))
+    mesh = jax.sharding.AbstractMesh((2, 2), ("stage", "data"))
     specs = shd.pipeline_state_pspec(shapes, mesh=mesh, zero1=False)
     for tree in (specs["params"], specs["opt"]):
         for s in jax.tree.leaves(tree, is_leaf=lambda x: isinstance(x, P)):
@@ -147,8 +147,7 @@ def test_pipeline_state_pspec_without_zero1_keeps_data_free():
 # 3-D (stage, data, model) composition: dp_partition_plan / ZeRO-2
 # ---------------------------------------------------------------------------
 
-_MESH3D = jax.sharding.AbstractMesh(
-    (("stage", 2), ("data", 2), ("model", 2)))
+_MESH3D = jax.sharding.AbstractMesh((2, 2, 2), ("stage", "data", "model"))
 
 
 def test_dp_partition_plan_skips_claimed_dims():
@@ -222,7 +221,7 @@ def test_sharded_state_bytes_shrink_by_mesh_factors():
     cfg = reduced_config("yi-6b")
     tcfg = TrainConfig(optimizer="adamw")
     shapes = steps_lib.train_state_shapes(cfg, tcfg)
-    mesh2d = jax.sharding.AbstractMesh((("stage", 2), ("data", 2)))
+    mesh2d = jax.sharding.AbstractMesh((2, 2), ("stage", "data"))
     b3 = shd.sharded_state_bytes(
         shapes, shd.pipeline_state_pspec(shapes, mesh=_MESH3D, zero1=True),
         _MESH3D)

@@ -10,6 +10,7 @@ import json
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -130,6 +131,37 @@ def test_aot_cache_path_dedupes_across_seeds(tmp_path):
     c = _engine(0, k=4)                 # different depth set
     assert c.aot_cache_path(c.batch_specs_like(batch), root) \
         != a.aot_cache_path(sa, root)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compilation_cache_is_placed_from_outside(from_env, tmp_path,
+                                                  monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set in code;
+    without it the cache goes to the one fixed path in the checkout."""
+    import jax
+
+    from repro.engine import stepcache
+    knobs = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in knobs}
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = stepcache.enable_compilation_cache()
+        if from_env:
+            assert path == tmp_path
+            assert jax.config.jax_compilation_cache_dir == \
+                saved["jax_compilation_cache_dir"]
+        else:
+            assert path == stepcache.DEFAULT_COMPILATION_CACHE
+            assert path == Path(__file__).resolve().parents[1] / ".jax_cache"
+            assert jax.config.jax_compilation_cache_dir == str(path)
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -321,12 +353,15 @@ def test_spatial_live_session_end_to_end(tmp_path):
     """The CLI flow the CI smoke runs: 2 jobs on 2 disjoint submeshes,
     genuinely concurrent rounds, cross-job step-cache hits."""
     out = tmp_path / "session.json"
+    # a compilation cache of its own, empty: compiles served warm from the
+    # checkout's cache reorder the rounds this test reads
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.cluster", "--jobs", "2",
          "--machines", "2", "--workers", "2", "--iters", "2",
          "--arrival", "0.0", "--spatial", "--quiet",
          "--json-out", str(out)],
-        capture_output=True, text=True, timeout=900, env=ENV)
+        capture_output=True, text=True, timeout=900,
+        env={**ENV, "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")})
     assert r.returncode == 0, r.stderr[-4000:]
     rec = json.loads(out.read_text())
     assert rec["spatial"] is True

@@ -67,15 +67,14 @@ def test_stage_param_specs_compose_stage_then_model():
     cfg = reduced_config("yi-6b")
     stacked = jax.eval_shape(lambda: st.stack_stage_params(
         lm.init_lm(jax.random.key(0), cfg)["groups"], cfg, 2))
-    mesh3 = jax.sharding.AbstractMesh(
-        (("stage", 2), ("data", 2), ("model", 2)))
+    mesh3 = jax.sharding.AbstractMesh((2, 2, 2), ("stage", "data", "model"))
     specs = st.stage_param_specs(stacked, mesh=mesh3)
     assert specs[0]["mixer"]["wq"] == P("stage", None, None, "model")
     assert specs[0]["mixer"]["wo"] == P("stage", None, "model")
     assert specs[0]["ffn"]["wu"] == P("stage", None, None, "model")
     assert specs[0]["ffn"]["wd"] == P("stage", None, "model")
     assert specs[0]["ln1"] == P("stage")
-    mesh1 = jax.sharding.AbstractMesh((("stage", 2),))
+    mesh1 = jax.sharding.AbstractMesh((2,), ("stage",))
     flat = jax.tree.leaves(st.stage_param_specs(stacked, mesh=mesh1),
                            is_leaf=lambda x: isinstance(x, P))
     assert flat and all(s == P("stage") for s in flat)
