@@ -1,0 +1,20 @@
+"""Published peak rates of each accelerator, keyed by ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture): one
+v5e chip has 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s.
+A device kind that is not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None

@@ -1,0 +1,195 @@
+"""Reduction of a profiler trace (``*.xplane.pb``) to what the per-layer
+metrics read.
+
+Device planes are ``/device:TPU:<n>``; on each, the ``XLA Ops`` line holds
+the executed HLO instructions (nested: a ``while`` spans its body's ops)
+and ``XLA Modules`` one event per executed program.  Host spans are the
+benchmark's own ``TraceAnnotation``s, named ``bench.*``.  Host and device
+events share one clock in the trace.
+
+Pallas calls carry no kernel name in the trace: each is a ``custom-call``
+instruction named after the jitted wrapper that launched it
+(``_flash_attention_jit``, ``_ssd_jit``), and its kind is read from the
+types of its results.
+"""
+from __future__ import annotations
+
+import collections
+import glob
+import os
+import re
+from typing import Dict, List, Optional, Tuple
+
+Interval = Tuple[int, int]
+
+_TYPE = re.compile(r"\b(bf16|f32|f16|s32|u32|pred)\[([0-9,]*)\]")
+
+
+def find(trace_dir: str) -> str:
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if len(files) != 1:
+        raise FileNotFoundError(f"expected one .xplane.pb under {trace_dir}, "
+                                f"found {files}")
+    return files[0]
+
+
+def load(path: str) -> Dict:
+    """Device ops and modules per device, and the host's bench spans."""
+    import jax
+    pd = jax.profiler.ProfileData.from_file(path)
+    devices: Dict[str, Dict[str, list]] = {}
+    host: List[Tuple[int, int, str]] = []
+    for plane in pd.planes:
+        if plane.name.startswith("/device:TPU:"):
+            dev = {"ops": [], "modules": []}
+            for line in plane.lines:
+                key = {"XLA Ops": "ops", "XLA Modules": "modules"}.get(line.name)
+                if key:
+                    dev[key] = [(int(e.start_ns), int(e.end_ns), e.name)
+                                for e in line.events]
+            devices[plane.name] = dev
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                host += [(int(e.start_ns), int(e.end_ns), e.name)
+                         for e in line.events if e.name.startswith("bench.")]
+    return {"devices": devices, "host": sorted(host)}
+
+
+def clip(ivs, lo, hi) -> List[Interval]:
+    return [(max(a, lo), min(b, hi)) for a, b, *_ in ivs
+            if min(b, hi) > max(a, lo)]
+
+
+def union(ivs) -> List[Interval]:
+    out: List[List[int]] = []
+    for a, b in sorted(ivs):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return [tuple(x) for x in out]
+
+
+def total(ivs) -> int:
+    return sum(b - a for a, b in ivs)
+
+
+def gaps(busy: List[Interval], lo: int, hi: int) -> List[Interval]:
+    out, t = [], lo
+    for a, b in busy:
+        if a > t:
+            out.append((t, a))
+        t = max(t, b)
+    if hi > t:
+        out.append((t, hi))
+    return out
+
+
+def self_times(ops) -> List[Tuple[int, int, str, int]]:
+    """(start, end, name, self ns) of nested ops: each op's time less the
+    time of the ops it contains."""
+    ops = sorted(ops, key=lambda e: (e[0], -e[1]))
+    child = [0] * len(ops)
+    stack: List[int] = []
+    for i, (a, b, _) in enumerate(ops):
+        while stack and ops[stack[-1]][1] <= a:
+            stack.pop()
+        if stack:
+            child[stack[-1]] += b - a
+        stack.append(i)
+    return [(a, b, n, (b - a) - c) for (a, b, n), c in zip(ops, child)]
+
+
+def short(name: str) -> str:
+    """``%fusion.12 = bf16[2,4096]{...} fusion(...)`` -> ``fusion.12
+    bf16[2,4096]``."""
+    instr, _, rest = name.partition(" = ")
+    m = _TYPE.search(rest)
+    return instr.lstrip("%") + (f" {m.group(0)}" if m else "")
+
+
+def kernel_kind(name: str) -> Optional[str]:
+    """The kind of a Pallas call from its instruction, or None: by the
+    number and types of its results, and for flash attention's single
+    bf16 result by its operands (q, k, v for the forward; q, k, v, do,
+    lse, delta for dq)."""
+    instr, _, rest = name.partition(" = ")
+    if "custom-call(" not in rest:
+        return None
+    result, _, operands = rest.partition(" custom-call(")
+    outs = _TYPE.findall(result)
+    dtypes = [t for t, _ in outs]
+    if instr.startswith("%_flash_attention_jit"):
+        if len(outs) == 2:
+            return "flash_fwd_lse" if dtypes[1] == "f32" else "flash_dkv"
+        if len(outs) == 1 and dtypes[0] == "f32":
+            return "flash_delta"
+        if len(outs) == 1:
+            n_in = operands.split("custom_call_target")[0].count("%")
+            return "flash_dq" if n_in >= 6 else "flash_fwd"
+    if instr.startswith("%_ssd_jit"):
+        return {2: "ssd_fwd", 3: "ssd_fwd_states", 4: "ssd_bwd"}.get(len(outs))
+    return None
+
+
+def summarize(trace: Dict, lo: int, hi: int, step_depths: List,
+              top: int = 10) -> Dict:
+    """Busy time, per-step device time, kernel calls and the breakdown of
+    the traced window [lo, hi] (ns)."""
+    busy_ns, step_ns, kernels = [], [], collections.defaultdict(
+        lambda: [0, 0])
+    op_self = collections.Counter()
+    gap_list = []
+    host = trace["host"]
+    for dev_name, dev in sorted(trace["devices"].items()):
+        ops = clip(dev["ops"], lo, hi)
+        busy = union(ops)
+        busy_ns.append(total(busy))
+        mods = [(a, b) for a, b, n in dev["modules"]
+                if n.startswith("jit_step(") and a >= lo and b <= hi]
+        step_ns.append([b - a for a, b in mods])
+        named = [(a, b, n) for a, b, n in dev["ops"] if a >= lo and b <= hi]
+        for a, b, n, self_ns in self_times(named):
+            kind = kernel_kind(n)
+            if kind:
+                kernels[kind][0] += 1
+                kernels[kind][1] += b - a
+            depth = _depth_at(a, mods, step_depths)
+            op_self[f"d{depth}:{short(n)}" if depth is not None
+                    else short(n)] += self_ns
+        if dev_name.endswith(":0"):
+            for a, b in gaps(busy, lo, hi):
+                gap_list.append((b - a, _host_label(a, b, host)))
+    n_dev = max(len(busy_ns), 1)
+    gap_list.sort(reverse=True)
+    return {
+        "devices": len(busy_ns),
+        "window_s": (hi - lo) / 1e9,
+        "busy_s": sum(busy_ns) / n_dev / 1e9,
+        "step_device_s": [[x / 1e9 for x in s] for s in step_ns],
+        "kernels": {k: {"calls": v[0], "seconds": v[1] / 1e9}
+                    for k, v in sorted(kernels.items())},
+        "device_ops": [[n, s / 1e9 / n_dev]
+                       for n, s in op_self.most_common(top)],
+        "idle_gaps": [[label, ns / 1e9] for ns, label in gap_list[:top]],
+    }
+
+
+def _depth_at(t, mods, step_depths):
+    for i, (a, b) in enumerate(mods):
+        if a <= t < b:
+            return step_depths[i] if i < len(step_depths) else None
+    return None
+
+
+def _host_label(a: int, b: int, host) -> str:
+    """The innermost bench span that overlaps the gap the most."""
+    best, best_ns = "no host span", 0
+    for ha, hb, n in host:
+        if n == "bench.window":
+            continue
+        ov = min(b, hb) - max(a, ha)
+        if ov > best_ns:
+            best, best_ns = n, ov
+    return best
