@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ModelConfig
 
 #: Successors per token of :class:`MarkovLM`.
@@ -92,27 +93,32 @@ class Pipeline:
         self._feat_rng_seed = seed + 17
 
     def get_batch(self, step: int) -> Dict[str, jax.Array]:
+        """The batch of ``step``; the call is the span ``data.get_batch``
+        and the token draw inside it ``data.sample`` (:mod:`repro.obs`)."""
         cfg = self.cfg
         S = self.seq_len
         if cfg.frontend:
             S_text = S - cfg.frontend_tokens
         else:
             S_text = S
-        toks = self.lm.sample(self.batch, S_text, step=step, shard=self.shard)
-        out = {
-            "tokens": jnp.asarray(toks[:, :-1], jnp.int32),
-            "labels": jnp.asarray(toks[:, 1:], jnp.int32),
-        }
-        rng = np.random.default_rng(
-            np.random.SeedSequence([self._feat_rng_seed, step, self.shard]))
-        dt = jnp.dtype(cfg.dtype)
-        if cfg.enc_layers:
-            out["frames"] = jnp.asarray(
-                rng.normal(size=(self.batch, S, cfg.d_model)) * 0.1, dt)
-        elif cfg.frontend:
-            out["frontend"] = jnp.asarray(
-                rng.normal(size=(self.batch, cfg.frontend_tokens,
-                                 cfg.d_model)) * 0.1, dt)
+        with obs.span("data.get_batch", step=step):
+            with obs.span("data.sample"):
+                toks = self.lm.sample(self.batch, S_text, step=step,
+                                      shard=self.shard)
+            out = {
+                "tokens": jnp.asarray(toks[:, :-1], jnp.int32),
+                "labels": jnp.asarray(toks[:, 1:], jnp.int32),
+            }
+            rng = np.random.default_rng(np.random.SeedSequence(
+                [self._feat_rng_seed, step, self.shard]))
+            dt = jnp.dtype(cfg.dtype)
+            if cfg.enc_layers:
+                out["frames"] = jnp.asarray(
+                    rng.normal(size=(self.batch, S, cfg.d_model)) * 0.1, dt)
+            elif cfg.frontend:
+                out["frontend"] = jnp.asarray(
+                    rng.normal(size=(self.batch, cfg.frontend_tokens,
+                                     cfg.d_model)) * 0.1, dt)
         return out
 
 

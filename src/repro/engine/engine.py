@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import hashlib
 import json
-import time
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
@@ -36,6 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import dataclasses
 
+from repro import obs
 from repro.config import (ModelConfig, SPBConfig, TrainConfig, snap_depth,
                           snap_depth_to_stages)
 from repro.dist import sharding as shd
@@ -102,6 +102,7 @@ class SPBEngine:
         if parallelism not in ("spmd", "pipeline"):
             raise ValueError(f"unknown parallelism {parallelism!r}; "
                              f"known: spmd, pipeline")
+        obs.install()
         self.cfg = cfg
         self.tcfg = tcfg
         self.spb = spb_cfg or SPBConfig()
@@ -402,24 +403,24 @@ class SPBEngine:
         """Run one training step on the session state; the policy picks
         the depth unless ``depth`` overrides it (a table key: None, an
         int suffix depth, or 'mb').  Returns the metrics dict (state
-        advances in place — the previous state's buffers are donated)."""
+        advances in place — the previous state's buffers are donated)
+        as soon as the step is enqueued.  The call is the span
+        ``engine.train_step`` and the enqueue inside it ``engine.enqueue``
+        (:mod:`repro.obs`); the step's device time is in the trace."""
         if self.state is None:
             raise RuntimeError("call init_state()/attach_state() first")
         if step is None:
             step = self._auto_step
-        key = (self.depth_key_for_step(step) if depth is SPBEngine._POLICY
-               else depth)
-        fn = self.step_fn(key)
-        t0 = time.perf_counter()
-        with jax.sharding.set_mesh(self.mesh):
-            self.state, metrics = fn(self.state, batch)
-        if getattr(self.policy, "needs_step_time", False):
-            # async backends return at dispatch; a timing-driven policy
-            # needs true wall-clock, at the cost of pipelining
-            jax.block_until_ready(metrics)
-        self.policy.observe(step, time.perf_counter() - t0)
-        self.last_depth = key
-        self._auto_step = step + 1
+        with obs.span("engine.train_step", step=step) as span:
+            key = (self.depth_key_for_step(step)
+                   if depth is SPBEngine._POLICY else depth)
+            fn = self.step_fn(key)
+            with jax.sharding.set_mesh(self.mesh), obs.span("engine.enqueue"):
+                self.state, metrics = fn(self.state, batch)
+            self.last_depth = key
+            self._auto_step = step + 1
+            span.set_metadata(depth=str(key),
+                              compiles=obs.counters()["compiles"])
         return metrics
 
     # -- AOT: lower / compile / export / import ----------------------------

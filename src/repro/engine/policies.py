@@ -44,23 +44,8 @@ class DepthPolicy(Protocol):
         """Suffix depth for ``step`` (None = full backprop)."""
         ...
 
-    def observe(self, step: int, step_time_s: float) -> None:
-        """Optional feedback after a step.  The time is true wall-clock
-        only if the policy sets ``needs_step_time = True`` (the engine
-        then blocks on the step's outputs before measuring); otherwise,
-        on async backends it is merely dispatch time."""
-        ...
 
-
-class _ObserveMixin:
-    needs_step_time = False     # set True to make the engine block for
-                                # real wall-clock before observe()
-
-    def observe(self, step: int, step_time_s: float) -> None:  # noqa: D401
-        pass
-
-
-class FullBackpropPolicy(_ObserveMixin):
+class FullBackpropPolicy:
     """Always full backprop (SPB off / spatial, where the compiled step
     itself owns the per-worker depths)."""
 
@@ -68,7 +53,7 @@ class FullBackpropPolicy(_ObserveMixin):
         return None
 
 
-class CyclePolicy(_ObserveMixin):
+class CyclePolicy:
     """The temporal k-cycle with warmup, backed by TemporalSchedule.
 
     The deepest level leads the cycle so every layer trains from step 0:
@@ -95,7 +80,7 @@ class CyclePolicy(_ObserveMixin):
         self.schedule = self.schedule.rebalance(slow_positions)
 
 
-class CostModelPolicy(_ObserveMixin):
+class CostModelPolicy:
     """Budget-driven depth selection from jigsaw cost-model estimates.
 
     ``profile`` is a :class:`repro.jigsaw.costmodel.ModelProfile` (paper
@@ -130,7 +115,7 @@ class CostModelPolicy(_ObserveMixin):
         return self.schedule.depth_at(step)
 
 
-class SchedulerHookPolicy(_ObserveMixin):
+class SchedulerHookPolicy:
     """External depth control: the JobSpec-level scheduler calls
     :meth:`request_depth` (or :meth:`request_fraction` with the paper's
     per-worker backprop fraction) and the engine executes that depth on
@@ -183,10 +168,6 @@ class SchedulerHookPolicy(_ObserveMixin):
         if self.default is not None:
             return self.default.depth_for_step(step)
         return None
-
-    def observe(self, step: int, step_time_s: float) -> None:
-        if self.default is not None:
-            self.default.observe(step, step_time_s)
 
 
 def make_policy(name: str, cfg: ModelConfig, spb: SPBConfig, *,
