@@ -114,10 +114,14 @@ def _flash_attention_jit(q, k, v, causal, window, q_block, kv_block,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    q_block: int = 128, kv_block: int = 128,
+                    q_block: int | None = None, kv_block: int | None = None,
                     interpret: bool | None = None):
     """Differentiable flash attention (custom VJP: FlashAttention-2
-    backward kernels — see ``kernels/flash_attention_bwd.py``)."""
+    backward kernels — see ``kernels/flash_attention_bwd.py``).
+
+    q: (B, Sq, H, D); k, v: (B, Sk, K, D).  With the blocks left None each
+    kernel (forward, dq, dk/dv) tiles by ``flash_attention.flash_blocks``
+    from the shapes; blocks given here apply to every kernel."""
     return _flash_attention_jit(q, k, v, causal, window, q_block, kv_block,
                                 resolve_interpret(interpret))
 

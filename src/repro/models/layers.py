@@ -403,25 +403,28 @@ def init_attention(key, cfg: ModelConfig, dtype) -> Params:
 
 def _pallas_attention(q: Array, k: Array, v: Array, *, causal: bool,
                       window: int) -> Array:
-    """The differentiable Pallas flash-attention kernel.  A shape it
-    cannot tile raises, naming the shape: it never switches to the
-    blockwise path behind the caller's back.  On TPU the blocks must also
-    respect Mosaic's native tiling (sublane multiple of 8, lane dim 128)."""
+    """The differentiable Pallas flash-attention kernel, each kernel tiled
+    by ``flash_blocks``.  A shape it cannot tile raises, naming the shape
+    and the blocks: it never switches to the blockwise path behind the
+    caller's back.  On TPU the blocks must also respect Mosaic's native
+    tiling (sublane multiple of 8, lane dim 128)."""
+    from repro.kernels.flash_attention import flash_blocks
+    from repro.kernels.ops import flash_attention
     Sq, Sk = q.shape[1], k.shape[1]
     D = q.shape[-1]
-    qb, kb = min(128, Sq), min(128, Sk)
+    blocks = {kind: flash_blocks(Sq, Sk, kind)
+              for kind in ("fwd", "dq", "dkv")}
     on_tpu = jax.default_backend() == "tpu"
-    if (Sq % qb or Sk % kb or q.shape[2] % k.shape[2]
-            or (on_tpu and (qb % 8 or kb % 8 or D % 128))):
+    if (q.shape[2] % k.shape[2] or (on_tpu and D % 128)
+            or any(Sq % qb or Sk % kb or (on_tpu and (qb % 8 or kb % 8))
+                   for qb, kb in blocks.values())):
         raise ValueError(
             f"use_pallas: flash attention cannot tile q {q.shape} / kv "
-            f"{k.shape} (B, S, heads, head_dim) with blocks ({qb}, {kb}): "
-            f"sequence lengths must be whole blocks and q heads a multiple "
-            f"of kv heads; on TPU blocks must be multiples of 8 and "
-            f"head_dim a multiple of 128")
-    from repro.kernels.ops import flash_attention
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           q_block=qb, kv_block=kb)
+            f"{k.shape} (B, S, heads, head_dim) with (q, kv) blocks "
+            f"{blocks}: sequence lengths must be whole blocks and q heads "
+            f"a multiple of kv heads; on TPU blocks must be multiples of 8 "
+            f"and head_dim a multiple of 128")
+    return flash_attention(q, k, v, causal=causal, window=window)
 
 
 def attention_fwd(p: Params, x: Array, cfg: ModelConfig, *, kind: str,

@@ -21,23 +21,39 @@ def _grads(fn, q, k, v, ct):
                     argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("B,Sq,Sk,H,K,D,causal,window", [
-    (2, 128, 128, 4, 2, 32, True, 0),      # GQA causal
-    (1, 128, 128, 4, 4, 32, False, 0),     # MHA bidirectional
-    (2, 128, 128, 8, 1, 64, True, 0),      # MQA
-    (1, 256, 256, 2, 2, 64, True, 64),     # sliding window
-    (1, 128, 256, 2, 2, 32, False, 0),     # cross-shaped (Sq != Sk)
-])
-def test_flash_attention_vjp_matches_ref(B, Sq, Sk, H, K, D, causal, window):
+TOLS = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
+        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
+
+
+@pytest.mark.parametrize(
+    "B,Sq,Sk,H,K,D,causal,window,q_block,kv_block,dtype", [
+        (2, 128, 128, 4, 2, 32, True, 0, 64, 64, jnp.float32),   # GQA causal
+        (1, 128, 128, 4, 4, 32, False, 0, 64, 64, jnp.float32),  # MHA bidir
+        (2, 128, 128, 8, 1, 64, True, 0, 64, 64, jnp.float32),   # MQA
+        (1, 256, 256, 2, 2, 64, True, 64, 64, 64, jnp.float32),  # window
+        (1, 128, 256, 2, 2, 32, False, 0, 64, 64, jnp.float32),  # Sq != Sk
+        # GQA with G=8 over 4 x 8 and 8 x 4 tiles: fully visible, diagonal
+        # and skipped (clamped-copy) tiles in every kernel
+        (1, 512, 512, 16, 2, 64, True, 0, 128, 64, jnp.float32),
+        (1, 512, 512, 16, 2, 64, True, 0, 64, 128, jnp.bfloat16),
+        # windows that are no multiple of the tiles
+        (1, 512, 512, 2, 2, 64, True, 96, 64, 128, jnp.float32),
+        (1, 512, 512, 2, 2, 64, True, 200, 128, 64, jnp.bfloat16),
+        # the blocks flash_blocks picks, per kernel
+        (1, 256, 256, 4, 2, 64, True, 0, None, None, jnp.float32),
+    ])
+def test_flash_attention_vjp_matches_ref(B, Sq, Sk, H, K, D, causal, window,
+                                         q_block, kv_block, dtype):
     ks = jax.random.split(jax.random.key(0), 4)
-    q = jax.random.normal(ks[0], (B, Sq, H, D))
-    k = jax.random.normal(ks[1], (B, Sk, K, D))
-    v = jax.random.normal(ks[2], (B, Sk, K, D))
+    q = jax.random.normal(ks[0], (B, Sq, H, D), dtype)
+    k = jax.random.normal(ks[1], (B, Sk, K, D), dtype)
+    v = jax.random.normal(ks[2], (B, Sk, K, D), dtype)
     ct = jax.random.normal(ks[3], (B, Sq, H, D))
 
     def fa(q, k, v):
         return flash_attention(q, k, v, causal=causal, window=window,
-                               q_block=64, kv_block=64, interpret=True)
+                               q_block=q_block, kv_block=kv_block,
+                               interpret=True)
 
     def fr(q, k, v):
         return ref.attention_ref(q, k, v, causal=causal, window=window)
@@ -45,8 +61,8 @@ def test_flash_attention_vjp_matches_ref(B, Sq, Sk, H, K, D, causal, window):
     got = _grads(fa, q, k, v, ct)
     want = _grads(fr, q, k, v, ct)
     for g, w, name in zip(got, want, "qkv"):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=2e-4, atol=2e-4,
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32), **TOLS[dtype],
                                    err_msg=f"d{name} mismatch")
 
 

@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (flash_attention_fwd, flash_blocks,
+                                           kv_band, pair_mask, q_band,
+                                           tile_full, tile_visible)
 from repro.kernels.rglru import rglru_scan
 from repro.kernels.ssd import ssd_scan
 
@@ -14,38 +16,102 @@ TOLS = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
         jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
 
 
-@pytest.mark.parametrize("B,Sq,Sk,H,K,D", [
-    (2, 256, 256, 4, 2, 64),
-    (1, 128, 128, 4, 4, 32),
-    (2, 128, 128, 8, 1, 64),     # MQA
-    (1, 512, 512, 2, 2, 128),
+@pytest.mark.parametrize("B,Sq,Sk,H,K,D,q_block,kv_block", [
+    (2, 256, 256, 4, 2, 64, 64, 64),
+    (1, 128, 128, 4, 4, 32, 64, 64),
+    (2, 128, 128, 8, 1, 64, 64, 64),     # MQA
+    (1, 512, 512, 2, 2, 128, 64, 64),
+    # GQA with G=8 and unequal tiles: 4 x 8 and 8 x 4 tiles, so fully
+    # visible, diagonal and skipped (clamped-copy) tiles all occur
+    (1, 512, 512, 16, 2, 64, 128, 64),
+    (1, 512, 512, 16, 2, 64, 64, 128),
+    (1, 256, 256, 4, 2, 64, None, None),   # the blocks flash_blocks picks
 ])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, Sq, Sk, H, K, D, causal, dtype):
+def test_flash_attention_sweep(B, Sq, Sk, H, K, D, q_block, kv_block,
+                               causal, dtype):
     ks = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(ks[0], (B, Sq, H, D), dtype)
     k = jax.random.normal(ks[1], (B, Sk, K, D), dtype)
     v = jax.random.normal(ks[2], (B, Sk, K, D), dtype)
-    out = flash_attention_fwd(q, k, v, causal=causal,
-                              q_block=64, kv_block=64, interpret=True)
+    out = flash_attention_fwd(q, k, v, causal=causal, q_block=q_block,
+                              kv_block=kv_block, interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **TOLS[dtype])
 
 
-@pytest.mark.parametrize("window", [32, 64, 128])
-def test_flash_attention_window(window):
+@pytest.mark.parametrize("window,q_block,kv_block,dtype", [
+    (32, 64, 64, jnp.float32),
+    (64, 64, 64, jnp.float32),
+    (128, 64, 64, jnp.float32),
+    # windows that are no multiple of the tiles: window-edge tiles inside
+    # the band, skipped tiles on both sides of it
+    (96, 64, 128, jnp.float32),
+    (96, 128, 64, jnp.bfloat16),
+    (200, 128, 64, jnp.float32),
+])
+def test_flash_attention_window(window, q_block, kv_block, dtype):
     ks = jax.random.split(jax.random.key(1), 3)
     B, S, H, K, D = 1, 256, 2, 2, 64
-    q = jax.random.normal(ks[0], (B, S, H, D))
-    k = jax.random.normal(ks[1], (B, S, K, D))
-    v = jax.random.normal(ks[2], (B, S, K, D))
+    q = jax.random.normal(ks[0], (B, S, H, D), dtype)
+    k = jax.random.normal(ks[1], (B, S, K, D), dtype)
+    v = jax.random.normal(ks[2], (B, S, K, D), dtype)
     out = flash_attention_fwd(q, k, v, causal=True, window=window,
-                              q_block=64, kv_block=64, interpret=True)
+                              q_block=q_block, kv_block=kv_block,
+                              interpret=True)
     want = ref.attention_ref(q, k, v, causal=True, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), **TOLS[dtype])
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+def test_flash_blocks_divide_and_clamp(kind):
+    """The chosen tiles divide the sequence, clamp to it when it is
+    shorter than a tile, and at sequences that are multiples of 128 (all
+    the TPU takes) are multiples of 128 themselves."""
+    for S in (8, 64, 96, 128, 256, 384, 640, 1024, 2048, 4096, 32768):
+        qb, kb = flash_blocks(S, S, kind)
+        assert S % qb == 0 and S % kb == 0, (S, qb, kb)
+        if S <= 128:
+            assert (qb, kb) == (S, S)
+        if S % 128 == 0:
+            assert qb % 128 == 0 and kb % 128 == 0, (S, qb, kb)
+    # cross-shaped: each side clamps to its own length
+    assert flash_blocks(64, 4096, kind)[0] == 64
+    assert flash_blocks(4096, 64, kind)[1] == 64
+
+
+@pytest.mark.parametrize("q_block,kv_block,causal,window", [
+    (64, 64, True, 0),
+    (128, 64, True, 0),
+    (64, 128, True, 96),
+    (128, 64, False, 200),
+    (64, 64, False, 0),
+])
+def test_flash_tile_bands_match_the_mask(q_block, kv_block, causal, window):
+    """The clamped index maps load exactly the visible tiles: kv_band /
+    q_band hold a block iff ``tile_visible`` says the tile has a visible
+    pair, and ``tile_full`` holds iff every pair of ``pair_mask`` is."""
+    S = 512
+    nq, nk = S // q_block, S // kv_block
+    for i in range(nq):
+        qs = i * q_block
+        first, last = (int(x) for x in kv_band(qs, q_block, kv_block, nk,
+                                              causal, window))
+        for j in range(nk):
+            ks = j * kv_block
+            mask = np.asarray(pair_mask((q_block, kv_block), qs, ks, causal,
+                                        window))
+            visible = bool(tile_visible(qs, ks, q_block, kv_block, causal,
+                                        window))
+            assert visible == mask.any() == (first <= j <= last), (i, j)
+            assert bool(tile_full(qs, ks, q_block, kv_block, causal,
+                                  window)) == mask.all(), (i, j)
+            qf, ql = (int(x) for x in q_band(ks, q_block, kv_block, nq,
+                                            causal, window))
+            assert visible == (qf <= i <= ql), (i, j)
 
 
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [
@@ -134,12 +200,14 @@ def test_model_lru_matches_kernel():
 
 def test_pallas_attention_refuses_untileable_sequence():
     """With ``use_pallas`` a sequence that is not a whole number of kernel
-    blocks raises, naming the shape, instead of taking the blockwise
-    path."""
+    blocks raises, naming the shape and the blocks chosen, instead of
+    taking the blockwise path.  1088 is longer than any preferred tile and
+    no multiple of 128; a sequence shorter than the tile is one block."""
     from repro.models.layers import _pallas_attention
-    q = jnp.zeros((1, 192, 4, 16))
-    kv = jnp.zeros((1, 192, 2, 16))
-    with pytest.raises(ValueError, match=r"\(1, 192, 4, 16\)"):
+    q = jnp.zeros((1, 1088, 4, 16))
+    kv = jnp.zeros((1, 1088, 2, 16))
+    with pytest.raises(ValueError,
+                       match=r"\(1, 1088, 4, 16\).*'fwd': \(128, 128\)"):
         _pallas_attention(q, kv, kv, causal=True, window=0)
 
 

@@ -71,15 +71,20 @@ def _fwd_and_grad(f, direction):
 
 
 # backward: the residual forward, then delta, dq and dk/dv
-@pytest.mark.parametrize("direction,kernels", [("fwd", 1), ("bwd", 4)])
+@pytest.mark.parametrize("direction,kernels,S,block", [
+    ("fwd", 1, 2048, 128), ("bwd", 4, 2048, 128),
+    # the benchmark's sequence with the tiles flash_blocks picks per kernel
+    ("fwd", 1, 4096, None), ("bwd", 4, 4096, None),
+])
 def test_flash_attention_compiles_at_yi_6b_widths(one_chip, direction,
-                                                  kernels):
-    """yi-6b: 32 q heads, 4 kv heads, head_dim 128, S=2048, 128 blocks."""
+                                                  kernels, S, block):
+    """yi-6b: 32 q heads, 4 kv heads, head_dim 128; 128 blocks at S=2048,
+    the chosen ones at S=4096."""
     def f(q, k, v):
-        return ops.flash_attention(q, k, v, causal=True, q_block=128,
-                                   kv_block=128, interpret=False)
-    q = _spec(one_chip, (1, 2048, 32, 128))
-    kv = _spec(one_chip, (1, 2048, 4, 128))
+        return ops.flash_attention(q, k, v, causal=True, q_block=block,
+                                   kv_block=block, interpret=False)
+    q = _spec(one_chip, (1, S, 32, 128))
+    kv = _spec(one_chip, (1, S, 4, 128))
     assert _kernel_count(_fwd_and_grad(f, direction), q, kv, kv) >= kernels
 
 
