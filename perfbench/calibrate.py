@@ -60,9 +60,10 @@ def main(argv=None) -> int:
             for label, kw in CONTROL.items():
                 readings[label] = harness.reference_readings(
                     cell.hf, cell.job, seed, prog["batches"], prog["depths"],
-                    reference.Variant(**kw))
+                    reference.Variant(**kw), devices)
         ref = harness.reference_readings(cell.hf, cell.job, seed,
-                                         prog["batches"], prog["depths"])
+                                         prog["batches"], prog["depths"],
+                                         devices=devices)
         for label, r in readings.items():
             nums = harness.compare(r, ref, names)
             line = {"workload": cell.name, "seed": seed, "reading": label,

@@ -13,7 +13,9 @@ elementwise work are not counted.  The head counts ``vocab_size`` rows.
 
 ``kernel_cost`` gives one Pallas call's operations and bytes (HBM traffic
 of its operands and results, each read or written once) for the
-roofline share of that kernel, by the call's kind.
+roofline share of that kernel, by the call's kind, at the shape one
+device sees in one call: the rows of one microbatch on one ``data`` shard
+of the job's ``mesh``, and the heads of one ``model`` shard.
 """
 from __future__ import annotations
 
@@ -70,12 +72,25 @@ def step_flops(hf, job, depth: Optional[int]) -> float:
     return float(total)
 
 
+def call_shape(job) -> Dict[str, int]:
+    """What one kernel call on one device holds of the job: its rows (the
+    batch over the microbatches and the mesh's ``data`` axis) and its
+    share of the heads (one over the ``model`` axis); a job without
+    ``mesh`` runs on one device."""
+    mesh = job.get("mesh", {})
+    return {"rows": job["batch"] // job.get("microbatches", 1)
+            // mesh.get("data", 1), "model": mesh.get("model", 1)}
+
+
 def kernel_cost(hf, job, kind: str) -> Dict[str, float]:
-    """Flops and bytes of one call of a kernel kind, for one layer."""
-    b, s, d = _dims(hf, job)
+    """Flops and bytes of one call of a kernel kind, for one layer on one
+    device (:func:`call_shape`)."""
+    _, s, d = _dims(hf, job)
+    b, tp = call_shape(job)["rows"], call_shape(job)["model"]
     if kind.startswith("flash_"):
-        h, kv = hf["num_attention_heads"], hf["num_key_value_heads"]
-        dh = d // h
+        h = hf["num_attention_heads"] // tp
+        kv = hf["num_key_value_heads"] // tp
+        dh = d // hf["num_attention_heads"]
         q = b * h * s * dh * 2                # one bf16 (B, H, S, Dh) tensor
         kvb = b * kv * s * dh * 2
         rows = b * h * s * 4                  # one f32 (B, H, S, 1) vector

@@ -4,7 +4,9 @@ and the correctness comparison.
 The program under test is the SPB trainer's normal path:
 ``launch.train.build_engine`` -> ``SPBEngine`` (``compile_table``,
 ``attach_state``, ``train_step``), fed by its own data layer
-(``data.pipeline.Pipeline.get_batch``).  The benchmark makes the weights
+(``data.pipeline.Pipeline.get_batch``), on the job's mesh
+(:func:`make_mesh`: a pipeline job's ``stage`` x ``data`` x ``model``,
+else every chip on ``data``).  The benchmark makes the weights
 (``weights.py``), times the window, reads the device and its trace, and
 compares the first steps with the float32 reference (``reference.py``).
 
@@ -104,10 +106,10 @@ def program_config(hf: Dict[str, Any]):
                     ssm_chunk=s["chunk_size"], ssm_n_groups=s["ngroups"])
     got = {k: (getattr(cfg.ssm, k[4:]) if k.startswith("ssm_")
                else getattr(cfg, k)) for k in want}
-    # the trainer keeps the residual stream in its compute dtype; it has
-    # no option for the float32 residual that Mamba's configs publish
+    # a trainer without the option keeps the residual stream in its
+    # compute dtype, not in the float32 that Mamba's configs publish
     want["residual_in_fp32"] = hf.get("residual_in_fp32", False)
-    got["residual_in_fp32"] = False
+    got["residual_in_fp32"] = getattr(cfg, "residual_in_fp32", False)
     if got != want:
         raise ValueError(f"the trainer's config for {hf['trainer']} differs "
                          f"from the configuration file: "
@@ -142,6 +144,57 @@ def cycle_depths(job, hf) -> List[int]:
     return [max(1, math.ceil((j + 1) * n / k)) for j in range(k)]
 
 
+# -- meshes --------------------------------------------------------------------
+
+def make_mesh(job: Dict[str, Any], devices: list):
+    """The job's mesh over the cell's ``devices``: a pipeline job's
+    ``mesh`` (``stage`` x ``data`` x ``model``, through the trainer's
+    ``make_pipeline_mesh``), else every device on ``data``."""
+    import jax
+    if job["parallelism"] != "pipeline":
+        return jax.sharding.Mesh(
+            np.asarray(devices).reshape(len(devices), 1), ("data", "model"))
+    from repro.launch.mesh import make_pipeline_mesh
+    m = job["mesh"]
+    if m["stage"] * m["data"] * m["model"] != len(devices):
+        raise ValueError(f"the job's mesh {m} does not hold the cell's "
+                         f"{len(devices)} chips")
+    mesh = make_pipeline_mesh(m["stage"], data_parallel=m["data"],
+                              model_parallel=m["model"])
+    if {d.id for d in mesh.devices.flat} != {d.id for d in devices}:
+        raise ValueError(f"the pipeline mesh's devices "
+                         f"{[d.id for d in mesh.devices.flat]} are not the "
+                         f"cell's {[d.id for d in devices]}")
+    return mesh
+
+
+def stage_of(mesh) -> Optional[Dict[int, int]]:
+    """{device id: its pipeline stage}, or None for a mesh without one."""
+    if "stage" not in mesh.axis_names:
+        return None
+    axis = mesh.axis_names.index("stage")
+    return {int(d.id): int(i[axis]) for i, d in np.ndenumerate(mesh.devices)}
+
+
+def spread(shapes, mesh):
+    """Shardings that split each leaf of ``shapes`` over every device of
+    ``mesh``, on its largest dim that the device count divides (the last
+    of equal ones); a leaf with no such dim is whole on every device."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    n, axes = mesh.devices.size, tuple(mesh.axis_names)
+
+    def one(leaf):
+        dims = [i for i, s in enumerate(leaf.shape) if s % n == 0]
+        if not dims:
+            return NamedSharding(mesh, P())
+        at = max(dims, key=lambda i: (leaf.shape[i], i))
+        return NamedSharding(mesh, P(*[axes if i == at else None
+                                       for i in range(len(leaf.shape))]))
+
+    return jax.tree.map(one, shapes)
+
+
 # -- the program under test ----------------------------------------------------
 
 class Session:
@@ -153,13 +206,15 @@ class Session:
         from repro.launch.train import build_engine
         self.cell, self.hf, self.job = cell, cell.hf, cell.job
         self.cfg = program_config(cell.hf)
-        mesh = jax.sharding.Mesh(
-            np.asarray(devices).reshape(len(devices), 1), ("data", "model"))
+        mesh = make_mesh(self.job, devices)
         spb = self.job["spb"]
+        pipeline = ({"pipeline_schedule": self.job["schedule"],
+                     "tensor_parallel": self.job["mesh"]["model"]}
+                    if self.job["parallelism"] == "pipeline" else {})
         self.engine = build_engine(
             self.cfg, train_config(self.job),
             SPBConfig(mode=spb["mode"], k=spb.get("k", 4)), mesh,
-            parallelism=self.job["parallelism"])
+            parallelism=self.job["parallelism"], **pipeline)
         n = len(cycle_depths(self.job, self.hf))
         self.keys = [self.engine.depth_key_for_step(s) for s in range(n)]
         if sorted(depth_of(k, self.hf) for k in self.keys) != \
@@ -218,14 +273,19 @@ def check_batch(batch, vocab: int) -> None:
 
 def _norm_fns(hf, seed):
     """Per-leaf norms of a tree, and of its change from the step-0 f32
-    parameters of ``seed``."""
+    parameters of ``seed`` (made sharded as the tree is)."""
     import jax
     import jax.numpy as jnp
-    norms = jax.jit(lambda t: weights.leaf_norms(t, hf))
-    diff = jax.jit(lambda m, key: weights.leaf_norms(
-        jax.tree.map(jnp.subtract, m, weights.master_params(hf, key)), hf))
     key = weights.seed_key(seed)
-    return norms, lambda m: diff(m, key)
+    norms = jax.jit(lambda t: weights.leaf_norms(t, hf))
+
+    def change(m):
+        like = jax.tree.map(lambda a: a.sharding, m)
+        return jax.jit(lambda m, key: weights.leaf_norms(
+            jax.tree.map(jnp.subtract, m, jax.lax.with_sharding_constraint(
+                weights.master_params(hf, key), like)), hf))(m, key)
+
+    return norms, change
 
 
 def n_first_steps(sess: Session) -> int:
@@ -262,17 +322,27 @@ def first_steps(sess: Session) -> Dict[str, Any]:
 
 
 def reference_readings(hf, job, seed: int, batches, depths,
-                       variant=reference.Variant()) -> Dict[str, Any]:
+                       variant=reference.Variant(), devices=None
+                       ) -> Dict[str, Any]:
     """The same first steps, by the reference (or a variant of it put in
-    the program's place)."""
+    the program's place), on the cell's ``devices`` (default: the first):
+    the f32 parameters, their gradient and AdamW's moments each split
+    over all of them (:func:`spread`), the batch whole on each."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(devices or jax.devices()[:1]), ("all",))
     _, change = _norm_fns(hf, seed)
     job = dict(job, depths=cycle_depths(job, hf))
-    params = jax.jit(functools.partial(weights.master_params, hf))(
-        weights.seed_key(seed))
+    key = weights.seed_key(seed)
+    start = functools.partial(weights.master_params, hf)
+    pshard = spread(jax.eval_shape(start, key), mesh)
+    mshard = {"mu": pshard, "nu": pshard}
+    whole = NamedSharding(mesh, P())
+    params = jax.jit(start, out_shardings=pshard)(key)
     zeros = jax.jit(lambda p: {"mu": jax.tree.map(jnp.zeros_like, p),
-                               "nu": jax.tree.map(jnp.zeros_like, p)})
+                               "nu": jax.tree.map(jnp.zeros_like, p)},
+                    out_shardings=mshard)
 
     def grad(p, tokens, labels, *, depth):
         loss, g = reference.gradient(p, tokens, labels, hf=hf, job=job,
@@ -280,23 +350,29 @@ def reference_readings(hf, job, seed: int, batches, depths,
         return loss, g, weights.leaf_norms(g, hf)
 
     update = jax.jit(functools.partial(reference.adamw, job=job),
-                     donate_argnums=(0, 1))
+                     out_shardings=(pshard, mshard), donate_argnums=(0, 1))
     out = {"loss": [], "depths": list(depths)}
-    moments = None           # on the host between steps, so that the
-    #                          gradient's activations fit beside the rest
+    # on one device the moments wait on the host between steps, so that
+    # the gradient's activations fit beside the rest; split over several
+    # they stay
+    park = mesh.devices.size == 1
+    moments = None
     with jax.default_matmul_precision("highest"):
-        fns = {d: jax.jit(functools.partial(grad, depth=d))
+        fns = {d: jax.jit(functools.partial(grad, depth=d),
+                          out_shardings=(whole, pshard, whole))
                for d in set(depths)}
         for i, (batch, d) in enumerate(zip(batches, depths)):
-            loss, g, gn = fns[d](params, jnp.asarray(batch["tokens"]),
-                                 jnp.asarray(batch["labels"]))
+            loss, g, gn = fns[d](params,
+                                 jax.device_put(batch["tokens"], whole),
+                                 jax.device_put(batch["labels"], whole))
             out["loss"].append(float(loss))
             if i == 0:
                 out["grad"] = np.asarray(gn)
-            m = zeros(params) if moments is None else jax.device_put(moments)
+            m = (zeros(params) if moments is None
+                 else jax.device_put(moments, mshard))
             params, m = update(params, m, g, i)
             del g
-            moments = jax.device_get(m)
+            moments = jax.device_get(m) if park else m
             del m
         out["change"] = np.asarray(change(params))
     del params, moments
@@ -439,6 +515,7 @@ def run(cell: registry.Cell, seed: int, seconds: float, trace: bool,
     pk = peaks_lib.peaks(kind) if devices[0].platform == "tpu" else None
     log(f"start {time.perf_counter() - t_start:.1f} s")
     sess = Session(cell, seed, devices)
+    stages = stage_of(sess.engine.mesh)
     log(f"weights and compiles {time.perf_counter() - t_start:.1f} s")
     prog = first_steps(sess)
     first = n_first_steps(sess)
@@ -466,7 +543,8 @@ def run(cell: registry.Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     t_ref = time.perf_counter()
-    ref = reference_readings(hf, job, seed, prog["batches"], prog["depths"])
+    ref = reference_readings(hf, job, seed, prog["batches"], prog["depths"],
+                             devices=devices)
     log(f"reference {time.perf_counter() - t_ref:.1f} s")
     numbers = compare(prog, ref, weights.leaf_names(hf))
     checks = judge(numbers, cell.limits["limits"])
@@ -487,13 +565,14 @@ def run(cell: registry.Cell, seed: int, seconds: float, trace: bool,
                                          "unit": m["unit"]}
                              for m in cell.end_to_end}
     else:
-        from perfbench import tracefile
+        from perfbench import readers, tracefile
         tr = tracefile.load(tracefile.find(str(trace_dir)))
         lo = [a for a, b, nm in tr["host"] if nm == "bench.window"]
         hi = [b for a, b, nm in tr["host"] if nm == "bench.window"]
         summary = tracefile.summarize(tr, lo[0], hi[0],
                                       [s["depth"] for s in win["steps"]])
         record = {"hf": hf, "job": job, "chips": len(devices), "peaks": pk,
+                  "stage_of": stages,
                   "steps": win["steps"], "window_s": win["window_s"],
                   "temp_bytes": temp, "trace": summary,
                   "flops": {d: counts.step_flops(hf, job, d)
@@ -507,11 +586,14 @@ def run(cell: registry.Cell, seed: int, seconds: float, trace: bool,
         device.update(busy_s=summary["busy_s"], window_s=summary["window_s"])
         result["breakdown"] = {"device_ops": summary["device_ops"],
                                "idle_gaps": summary["idle_gaps"]}
+        if stages:
+            result["breakdown"]["stages"] = readers.stage_split(record)
     result["device"] = device
     result["setup"] = {"setup_s": setup_s, "in_window_bytes_in_use_max":
                        win["in_use_bytes"], "step_temp_bytes": temp,
                        "memory_stats_after_window": stats,
                        "left_out_of_change": numbers["left_out"],
+                       "first_steps_depths": prog["depths"],
                        "slow_steps": slow_steps(win["steps"])}
     result["checks"] = checks
     return result

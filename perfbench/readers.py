@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import statistics
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from perfbench import counts
 
@@ -41,3 +41,41 @@ def roofline_share(rec: Dict, prefix: str) -> Optional[float]:
                                   c["bytes"] / pk["hbm_bytes_per_s"])
         seconds += k["seconds"]
     return 100.0 * ideal / seconds if seconds else None
+
+
+def _stages(rec: Dict) -> Optional[Dict[int, Dict[str, float]]]:
+    """{stage: seconds in the step programs (``tracefile.in_steps``) of
+    its devices, summed, with their count ``devices``} of a pipeline
+    cell's traced window; None for a cell without stages or a trace."""
+    tr, where = rec.get("trace"), rec.get("stage_of")
+    if not tr or not where or not tr.get("in_steps_s"):
+        return None
+    out: Dict[int, Dict[str, float]] = {}
+    for plane, t in tr["in_steps_s"].items():
+        s = out.setdefault(where[int(plane.rsplit(":", 1)[1])],
+                           {"devices": 0})
+        s["devices"] += 1
+        for k, v in t.items():
+            s[k] = s.get(k, 0.0) + v
+    return out
+
+
+def pipeline_share(rec: Dict, key: str) -> Optional[float]:
+    """Every device's ``key`` seconds inside its step programs, summed,
+    over those programs' seconds summed, in %."""
+    st = _stages(rec)
+    module = sum(s["module"] for s in st.values()) if st else 0.0
+    return 100.0 * sum(s[key] for s in st.values()) / module if module \
+        else None
+
+
+def stage_split(rec: Dict) -> Optional[List]:
+    """[name, seconds] a device of each stage spends in its step programs,
+    idle in them and in exposed collectives, for the result's
+    breakdown."""
+    st = _stages(rec)
+    if not st:
+        return None
+    return [[f"stage {s} {k.replace('_', ' ')}", t[k] / t["devices"]]
+            for s, t in sorted(st.items())
+            for k in ("module", "idle", "exposed_collective")]

@@ -7,7 +7,9 @@ files and entries:
 * ``BENCHMARK.json`` ``configs[].file``: the configuration (published
   keys as run, the trainer's architecture and overrides, departures);
 * ``perfbench/traffic/<traffic>.json``: the job (batch, sequence length,
-  SPB mode and k, parallelism, optimizer);
+  SPB mode and k, parallelism, microbatches, optimizer; a pipeline job
+  also its ``mesh`` of ``stage``, ``data`` and ``model`` sizes and its
+  ``schedule``);
 * ``perfbench/limits/<workload>.json``: the limits of the correctness
   comparison, with the readings they were set from;
 * ``perfbench/metrics/<metric>.py``: the reader of one per-layer metric,

@@ -75,3 +75,23 @@ def test_ssd_kernel_costs():
     assert st["bytes"] - fwd["bytes"] == bh * chunks * p * n * 4
     with pytest.raises(ValueError):
         counts.kernel_cost(MAMBA, JOB, "ssd_nothing")
+
+
+def test_flash_cost_at_the_call_shape():
+    # 8 rows in 2 microbatches over 2 data shards: 2 rows a call; 2-way
+    # tensor parallel: 2 of the 4 query heads and 1 of the 2 kv heads
+    job = dict(JOB, batch=8, microbatches=2,
+               mesh={"stage": 2, "data": 2, "model": 2})
+    assert counts.call_shape(job) == {"rows": 2, "model": 2}
+    mm = 2 * 2 * 2 * 16 * 36
+    q, kv, rows = 2 * 2 * 8 * 16 * 2, 2 * 1 * 8 * 16 * 2, 2 * 2 * 8 * 4
+    assert counts.kernel_cost(LLAMA, job, "flash_fwd_lse") == {
+        "flops": 2 * mm, "bytes": 2 * q + 2 * kv + rows}
+    assert counts.kernel_cost(LLAMA, job, "flash_dkv")["bytes"] == \
+        2 * q + 4 * kv + 2 * rows
+    # one device and one microbatch: the job's whole batch and heads
+    one = dict(JOB, microbatches=1, mesh={"stage": 1, "data": 1, "model": 1})
+    for kind in ("flash_fwd", "flash_fwd_lse", "flash_delta", "flash_dq",
+                 "flash_dkv"):
+        assert counts.kernel_cost(LLAMA, one, kind) == \
+            counts.kernel_cost(LLAMA, JOB, kind)

@@ -29,3 +29,48 @@ def test_float32_residual_is_refused():
     with pytest.raises(ValueError, match="residual_in_fp32"):
         harness.program_config(hf)
     harness.program_config(dict(hf, residual_in_fp32=False))
+
+
+class _Trainer:
+    """The trainer's config with a ``residual_in_fp32`` option of its own."""
+
+    def __init__(self, cfg, residual_in_fp32):
+        self._cfg, self.residual_in_fp32 = cfg, residual_in_fp32
+
+    def __getattr__(self, name):
+        return getattr(self._cfg, name)
+
+
+@pytest.mark.parametrize("config", ["mamba2_12l", "yi6b_2l"])
+@pytest.mark.parametrize("option", [True, False])
+def test_residual_in_fp32_read_from_the_trainer(config, option, monkeypatch):
+    harness.import_program(registry.ROOT)
+    import repro.configs as configs
+    real = configs.get_config
+
+    class Arch:
+        def __init__(self, arch):
+            self.arch = arch
+
+        def scaled(self, **kw):
+            return _Trainer(real(self.arch).scaled(**kw), option)
+
+    monkeypatch.setattr(configs, "get_config", Arch)
+    hf = registry.canonical(json.loads(
+        (registry.ROOT / f"perfbench/configs/{config}.json").read_text()))
+    if option == hf.get("residual_in_fp32", False):
+        assert harness.program_config(hf).residual_in_fp32 is option
+    else:
+        with pytest.raises(ValueError, match="residual_in_fp32"):
+            harness.program_config(hf)
+
+
+def test_mesh_from_the_job():
+    import jax
+    one = jax.devices()[:1]
+    spmd = harness.make_mesh({"parallelism": "spmd"}, one)
+    assert dict(spmd.shape) == {"data": 1, "model": 1}
+    assert harness.stage_of(spmd) is None
+    with pytest.raises(ValueError, match="does not hold"):
+        harness.make_mesh({"parallelism": "pipeline",
+                           "mesh": {"stage": 2, "data": 1, "model": 2}}, one)

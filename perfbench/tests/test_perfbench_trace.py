@@ -79,3 +79,34 @@ def test_helpers():
         "%_ssd_jit.3 = (f32[8,64,64]{2,1,0}, f32[8,64,128]{2,1,0}) "
         "custom-call(bf16[8,64,64]{2,1,0} %a)") == "ssd_fwd"
     assert tracefile.kernel_kind("%fusion.2 = f32[2] fusion()") is None
+
+
+#: flash_attention_roofline and mfu of the two stored yi6b_2l traces, read
+#: with the cell's record before kernel calls were priced at the shape of
+#: one device's microbatch (the widths of the spans trace's sidecar; the
+#: window's length from the trace).
+BEFORE = {"yi6b_2l.spb_k2": (18.060357973898288, 0.467217366558213),
+          "yi6b_2l.spb_k2.spans": (18.146685505537654, 1.2400137849229567)}
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+@pytest.mark.parametrize("mesh", [None, {"stage": 1, "data": 1, "model": 1}])
+def test_one_chip_readings_unchanged_by_the_call_shape(name, mesh):
+    import json
+
+    from perfbench import counts, registry
+    side = json.loads((DATA / "yi6b_2l.spb_k2.spans.json").read_text())
+    job = dict(side["job"], **({"mesh": mesh} if mesh else {}))
+    tr = tracefile.load(str(DATA / f"{name}.xplane.pb"))
+    (lo, hi), = [(a, b) for a, b, n in tr["host"] if n == "bench.window"]
+    n = sum(1 for *_, nm in tr["host"] if nm == "bench.train_step")
+    depths = [[2, 1][(3 + i) % 2] for i in range(n)]
+    s = tracefile.summarize(tr, lo, hi, depths)
+    rec = {"hf": side["hf"], "job": job, "peaks": side["peaks"], "chips": 1,
+           "steps": [{"depth": d} for d in depths], "trace": s,
+           "window_s": s["window_s"],
+           "flops": {d: counts.step_flops(side["hf"], job, d)
+                     for d in set(depths)}}
+    flash, mfu = BEFORE[name]
+    assert registry.reader("flash_attention_roofline")(rec) == flash
+    assert registry.reader("mfu")(rec) == mfu
