@@ -79,35 +79,26 @@ def enable_cache() -> None:
 
 def program_config(hf: Dict[str, Any]):
     """The trainer's ModelConfig for a configuration file, checked against
-    the file's published keys."""
+    the file's published keys: the shared ones and its architecture's
+    ``program_keys``."""
     from repro.configs import get_config
-    from repro.config import SSMConfig
     run = hf["trainer"]
-    overrides = dict(run["overrides"])
-    if isinstance(overrides.get("ssm"), dict):
-        overrides["ssm"] = SSMConfig(**overrides["ssm"])
-    cfg = get_config(run["arch"]).scaled(**overrides)
+    base = get_config(run["arch"])
+    # an override given as a dict is a group of the trainer's config, made
+    # as that group's own type
+    overrides = {k: type(getattr(base, k))(**v) if isinstance(v, dict)
+                 else v for k, v in run["overrides"].items()}
+    cfg = base.scaled(**overrides)
     want = {"d_model": hf["hidden_size"],
             "num_layers": hf["num_hidden_layers"],
             "vocab_size": hf["vocab_size"],
             "norm_eps": hf["rms_norm_eps"],
             "tie_embeddings": hf["tie_word_embeddings"],
-            "dtype": hf["torch_dtype"]}
-    if hf["model_type"] == "llama":
-        want.update(num_heads=hf["num_attention_heads"],
-                    num_kv_heads=hf["num_key_value_heads"],
-                    d_ff=hf["intermediate_size"],
-                    rope_theta=hf["rope_theta"],
-                    head_dim=hf["hidden_size"] // hf["num_attention_heads"])
-    else:
-        s = hf["ssm_cfg"]
-        want.update(ssm_d_state=s["d_state"], ssm_d_conv=s["d_conv"],
-                    ssm_expand=s["expand"], ssm_head_dim=s["headdim"],
-                    ssm_chunk=s["chunk_size"], ssm_n_groups=s["ngroups"])
-    got = {k: (getattr(cfg.ssm, k[4:]) if k.startswith("ssm_")
-               else getattr(cfg, k)) for k in want}
+            "dtype": hf["torch_dtype"],
+            **registry.arch(hf["model_type"]).program_keys(hf)}
+    got = {k: functools.reduce(getattr, k.split("."), cfg) for k in want}
     # a trainer without the option keeps the residual stream in its
-    # compute dtype, not in the float32 that Mamba's configs publish
+    # compute dtype, not in the float32 that some published configs state
     want["residual_in_fp32"] = hf.get("residual_in_fp32", False)
     got["residual_in_fp32"] = getattr(cfg, "residual_in_fp32", False)
     if got != want:

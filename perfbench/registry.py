@@ -13,15 +13,35 @@ files and entries:
 * ``perfbench/limits/<workload>.json``: the limits of the correctness
   comparison, with the readings they were set from;
 * ``perfbench/metrics/<metric>.py``: the reader of one per-layer metric,
-  a ``read(record)`` that returns a number or None.
+  a ``read(record)`` that returns a number or None;
+* ``perfbench/archs/<model_type>.py``: one architecture, for the
+  configurations of its ``model_type``: ``layer_spec(hf)``, the weight
+  layout of one layer (path -> (shape, dtype, init rule), a rule being a
+  name that ``weights.py`` knows or a function (key, shape) -> float32
+  values); ``layer(p, x, hf, low)``, one layer of the float32 reference,
+  and optionally ``stack(params, x, hf, low, boundary)``, the whole stack
+  where its layers differ by kind (default ``reference.scan_layers``);
+  ``layer_flops(hf, job)``, one layer's forward and entry-projection
+  flops; ``program_keys(hf)``, the trainer's config attributes it checks
+  (dots descend into a group) with the file's values; ``STAND_IN``, the
+  CPU stand-in's (published-key updates, trainer overrides); optionally
+  ``ALIASES``, published key names that mean the Llama-style names the
+  benchmark reads;
+* ``perfbench/kernels/<family>.py``: one family of Pallas calls, whose
+  kinds are named ``<family>_<what>`` (a family's name has no ``_``):
+  ``WRAPPER``, the jitted wrapper that launches them, as the trace names
+  the call; ``KINDS``; ``kind(results, operands)``, a call's kind (or
+  None) from its results' (dtype, dims) pairs and its operands' text;
+  ``cost(hf, job, kind)``, one call's flops and bytes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 ROOT = Path(__file__).resolve().parent.parent
 HERE = "perfbench"
@@ -40,17 +60,12 @@ class Cell:
     per_layer: List[Dict[str, Any]]
 
 
-#: Published key names that mean what the Llama-style names the benchmark
-#: reads mean (mamba_ssm's config.json uses the former).
-ALIASES = {"d_model": "hidden_size", "n_layer": "num_hidden_layers",
-           "tie_embeddings": "tie_word_embeddings",
-           "norm_epsilon": "rms_norm_eps"}
-
-
 def canonical(hf: Dict[str, Any]) -> Dict[str, Any]:
-    """A configuration file with the Llama-style names added."""
+    """A configuration file with the Llama-style names added (its
+    architecture's ``ALIASES``)."""
     out = dict(hf)
-    for published, name in ALIASES.items():
+    for published, name in getattr(arch(hf["model_type"]), "ALIASES",
+                                   {}).items():
         if published in hf:
             out.setdefault(name, hf[published])
     return out
@@ -93,13 +108,44 @@ def cell(name: str, root: Path = ROOT) -> Cell:
                            if _reports(m, name)])
 
 
+@functools.lru_cache(maxsize=None)
+def _module(path: Path, label: str):
+    spec = importlib.util.spec_from_file_location(label, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, root: Path = ROOT) -> Callable[[Dict], Any]:
     """The ``read`` function of ``perfbench/metrics/<metric>.py``."""
     path = Path(root) / HERE / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"perfbench_metric_{metric.replace('.', '_')}", path)
-    if spec is None or not path.exists():
+    if not path.exists():
         raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"perfbench_metric_{metric.replace('.', '_')}").read
+
+
+def arch(model_type: str, root: Path = ROOT):
+    """The module ``perfbench/archs/<model_type>.py``."""
+    path = Path(root) / HERE / "archs" / f"{model_type}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"no architecture for model_type "
+                                f"{model_type!r}: no file {path}")
+    return _module(path, f"perfbench_arch_{model_type}")
+
+
+def kernel_family(kind: str, root: Path = ROOT):
+    """The module ``perfbench/kernels/<family>.py`` of a kernel kind
+    ``<family>_<what>``."""
+    family = kind.partition("_")[0]
+    path = Path(root) / HERE / "kernels" / f"{family}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"no kernel family for kind {kind!r}: no "
+                                f"file {path}")
+    return _module(path, f"perfbench_kernel_{family}")
+
+
+@functools.lru_cache(maxsize=None)
+def kernel_families(root: Path = ROOT) -> Tuple[Any, ...]:
+    """Every module under ``perfbench/kernels/``, by name."""
+    return tuple(_module(p, f"perfbench_kernel_{p.stem}") for p in
+                 sorted((Path(root) / HERE / "kernels").glob("*.py")))

@@ -1,5 +1,10 @@
 """The benchmark's FLOP and byte counts against hand counts at a tiny
-size, the head and a truncated depth included."""
+size, the head and a truncated depth included, and every configuration
+and traffic file's counts against those recorded before the
+architectures and kernel families moved into files of their own."""
+import json
+from pathlib import Path
+
 import pytest
 
 from perfbench import counts
@@ -95,3 +100,27 @@ def test_flash_cost_at_the_call_shape():
                  "flash_dkv"):
         assert counts.kernel_cost(LLAMA, one, kind) == \
             counts.kernel_cost(LLAMA, JOB, kind)
+
+
+READINGS = json.loads((Path(__file__).resolve().parent / "data"
+                       / "readings.json").read_text())
+REPO = Path(__file__).resolve().parents[2]
+
+
+@pytest.mark.parametrize("pair", sorted(READINGS["counts"]))
+def test_counts_unchanged(pair):
+    """Every configuration file under every traffic file: the step's flops
+    at every depth and each kind's call cost equal the recorded ones."""
+    from perfbench import registry
+    config, traffic = pair.split("|")
+    hf = registry.canonical(json.loads(
+        (REPO / "perfbench/configs" / f"{config}.json").read_text()))
+    job = json.loads(
+        (REPO / "perfbench/traffic" / f"{traffic}.json").read_text())
+    want = READINGS["counts"][pair]
+    assert {d: counts.step_flops(hf, job, None if d == "None" else int(d))
+            for d in want["step_flops"]} == want["step_flops"]
+    assert len(want["step_flops"]) == hf["num_hidden_layers"] + 1
+    assert want["kernel_cost"]
+    assert {k: counts.kernel_cost(hf, job, k)
+            for k in want["kernel_cost"]} == want["kernel_cost"]

@@ -19,31 +19,26 @@ def test_judge_needs_every_limit():
         harness.judge(NUMBERS, dict(full, loss_gaps=1e-2))
 
 
-def test_float32_residual_is_refused():
-    # Mamba-2's published config keeps the residual in float32; the
-    # trainer has no such option, so the file cannot claim it
-    harness.import_program(registry.ROOT)
-    hf = registry.canonical(json.loads(
-        (registry.ROOT / "perfbench/configs/mamba2_12l.json").read_text()))
-    assert hf["residual_in_fp32"] is True
-    with pytest.raises(ValueError, match="residual_in_fp32"):
-        harness.program_config(hf)
-    harness.program_config(dict(hf, residual_in_fp32=False))
-
-
 class _Trainer:
-    """The trainer's config with a ``residual_in_fp32`` option of its own."""
+    """The trainer's config with a ``residual_in_fp32`` option of its own,
+    or (``option`` None) with none, whatever the real config holds."""
 
-    def __init__(self, cfg, residual_in_fp32):
-        self._cfg, self.residual_in_fp32 = cfg, residual_in_fp32
+    def __init__(self, cfg, option):
+        self._cfg, self._option = cfg, option
 
     def __getattr__(self, name):
-        return getattr(self._cfg, name)
+        if name != "residual_in_fp32":
+            return getattr(self._cfg, name)
+        if self._option is None:
+            raise AttributeError(name)
+        return self._option
 
 
 @pytest.mark.parametrize("config", ["mamba2_12l", "yi6b_2l"])
-@pytest.mark.parametrize("option", [True, False])
+@pytest.mark.parametrize("option", [True, False, None])
 def test_residual_in_fp32_read_from_the_trainer(config, option, monkeypatch):
+    # a trainer without the option keeps the residual in its compute
+    # dtype, so a file that states float32 (Mamba-2's) is refused there
     harness.import_program(registry.ROOT)
     import repro.configs as configs
     real = configs.get_config
@@ -58,8 +53,10 @@ def test_residual_in_fp32_read_from_the_trainer(config, option, monkeypatch):
     monkeypatch.setattr(configs, "get_config", Arch)
     hf = registry.canonical(json.loads(
         (registry.ROOT / f"perfbench/configs/{config}.json").read_text()))
-    if option == hf.get("residual_in_fp32", False):
-        assert harness.program_config(hf).residual_in_fp32 is option
+    runs = bool(option)
+    if runs == hf.get("residual_in_fp32", False):
+        cfg = harness.program_config(hf)
+        assert getattr(cfg, "residual_in_fp32", False) is runs
     else:
         with pytest.raises(ValueError, match="residual_in_fp32"):
             harness.program_config(hf)

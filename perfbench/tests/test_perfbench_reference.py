@@ -1,13 +1,16 @@
 """The float32 reference gives the same readings with its parameters,
-gradient and moments split over four devices as on one (a tiny llama
+gradient and moments split over four devices as on one (a tiny
 stand-in on host CPU devices, in a child process: the device count is
-fixed when JAX starts)."""
+fixed when JAX starts), and the same readings as before the
+architectures moved into files of their own."""
 import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
+import pytest
 
 from perfbench import registry
 from perfbench.tests import tiny_cells
@@ -54,3 +57,35 @@ def test_sharded_reference_agrees_with_one_device(tmp_path):
         assert a.shape == b.shape and np.all(np.isfinite(a))
         # float32 rounding: sums taken in another order on four devices
         np.testing.assert_allclose(b, a, rtol=2e-5, atol=0, err_msg=k)
+
+
+READINGS = json.loads((Path(__file__).resolve().parent / "data"
+                       / "readings.json").read_text())["reference"]
+
+
+@pytest.mark.parametrize("pair", sorted(READINGS))
+def test_reference_unchanged(pair):
+    """The reference's losses, first gradient and change at each
+    configuration's CPU stand-in widths, with its weights in the recorded
+    dtype, equal, bit for bit, those recorded before the architectures
+    moved into files of their own."""
+    from perfbench import harness
+    harness.import_program(registry.ROOT)
+    config, traffic = pair.split("|")
+    pb = registry.ROOT / registry.HERE
+    want = READINGS[pair]
+    hf = registry.canonical(dict(tiny_cells.shrink(json.loads(
+        (pb / "configs" / f"{config}.json").read_text())),
+        torch_dtype=want["torch_dtype"]))
+    job = dict(json.loads((pb / "traffic" / f"{traffic}.json").read_text()),
+               seq_len=64)
+    rng = np.random.default_rng(7)
+    batches = []
+    for _ in want["depths"]:
+        t = rng.integers(0, hf["vocab_size"],
+                         (job["batch"], job["seq_len"] + 1), dtype=np.int32)
+        batches.append({"tokens": t[:, :-1], "labels": t[:, 1:]})
+    got = harness.reference_readings(hf, job, 2**31 + 5, batches,
+                                     want["depths"])
+    for k in ("loss", "grad", "change"):
+        assert np.asarray(got[k], np.float64).tolist() == want[k], k

@@ -2,6 +2,7 @@
 harness (``harness.run`` with ``trace=True``), committed under ``data/``:
 yi-6b and Mamba-2 cells with every width shrunk (2 and 12 layers, the
 cells' SPB cycles), a window of a few steps."""
+import json
 from pathlib import Path
 
 import pytest
@@ -110,3 +111,20 @@ def test_one_chip_readings_unchanged_by_the_call_shape(name, mesh):
     flash, mfu = BEFORE[name]
     assert registry.reader("flash_attention_roofline")(rec) == flash
     assert registry.reader("mfu")(rec) == mfu
+
+
+KERNELS = json.loads((DATA / "readings.json").read_text())["trace_kernels"]
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_calls_unchanged(name):
+    """The Pallas calls found in each stored trace, by kind, with their
+    device seconds, equal those recorded before the kernel families moved
+    into files of their own."""
+    tr = tracefile.load(str(DATA / f"{name}.xplane.pb"))
+    (lo, hi), = [(a, b) for a, b, n in tr["host"] if n == "bench.window"]
+    n = sum(1 for *_, nm in tr["host"] if nm == "bench.train_step")
+    cycle, first, _ = CYCLES[name.removesuffix(".spans")]
+    depths = [cycle[(first + i) % len(cycle)] for i in range(n)]
+    assert tracefile.summarize(tr, lo, hi, depths)["kernels"] == \
+        KERNELS[name]

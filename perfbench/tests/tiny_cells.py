@@ -10,21 +10,18 @@ from perfbench import registry
 
 REPO = registry.ROOT
 
-#: Widths of the stand-ins, by model_type: (published-key updates, trainer
-#: overrides).
-SHRINK = {
-    "llama": ({"hidden_size": 64, "intermediate_size": 128,
-               "num_attention_heads": 4, "num_key_value_heads": 2,
-               "vocab_size": 512},
-              {"d_model": 64, "num_heads": 4, "num_kv_heads": 2,
-               "head_dim": 16, "d_ff": 128, "vocab_size": 512,
-               "attn_q_block": 32, "attn_kv_block": 32, "use_pallas": False}),
-    "mamba2": ({"d_model": 64, "vocab_size": 500},
-               {"d_model": 64, "vocab_size": 500, "use_pallas": False,
-                "ssm": {"d_state": 16, "d_conv": 4, "expand": 2,
-                        "head_dim": 16, "chunk": 16}}),
-}
-SSM = {"d_state": 16, "headdim": 16, "chunk_size": 16}
+
+def shrink(hf):
+    """A configuration file's stand-in: its architecture's ``STAND_IN``
+    widths (published keys, a group merged key by key) and trainer
+    overrides."""
+    published, overrides = registry.arch(hf["model_type"]).STAND_IN
+    hf = dict(hf)
+    for k, v in published.items():
+        hf[k] = dict(hf.get(k, {}), **v) if isinstance(v, dict) else v
+    hf["trainer"] = dict(hf["trainer"], overrides=dict(
+        hf["trainer"]["overrides"], **overrides))
+    return hf
 
 
 def make_root(tmp: Path, workload: str, seq_len: int = 64) -> Path:
@@ -40,13 +37,7 @@ def make_root(tmp: Path, workload: str, seq_len: int = 64) -> Path:
     shutil.copytree(REPO / registry.HERE / "metrics", pb / "metrics",
                     dirs_exist_ok=True)
     entry = next(c for c in bench["configs"] if c["name"] == real.config_name)
-    hf = json.loads((REPO / entry["file"]).read_text())
-    published, overrides = SHRINK[hf["model_type"]]
-    hf.update(published)
-    if "ssm_cfg" in hf:
-        hf["ssm_cfg"] = dict(hf["ssm_cfg"], **SSM)
-    hf["trainer"] = dict(hf["trainer"], overrides=dict(
-        hf["trainer"]["overrides"], **overrides))
+    hf = shrink(json.loads((REPO / entry["file"]).read_text()))
     (tmp / entry["file"]).write_text(json.dumps(hf))
     job = dict(real.job, seq_len=seq_len)
     (pb / "traffic" / f"{real.traffic_name}.json").write_text(json.dumps(job))

@@ -10,9 +10,9 @@ benchmark's own ``TraceAnnotation``s, named ``bench.*``.  Host and device
 events share one clock in the trace.
 
 Pallas calls carry no kernel name in the trace: each is a ``custom-call``
-instruction named after the jitted wrapper that launched it
-(``_flash_attention_jit``, ``_ssd_jit``), and its kind is read from the
-types of its results.
+instruction named after the jitted wrapper that launched it, and its
+kind is read from its results and operands.  Both are the kernel
+family's (``perfbench/kernels/<family>.py``: ``WRAPPER`` and ``kind``).
 """
 from __future__ import annotations
 
@@ -23,6 +23,8 @@ import glob
 import os
 import re
 from typing import Dict, List, Optional, Tuple
+
+from perfbench import registry
 
 Interval = Tuple[int, int]
 
@@ -165,26 +167,16 @@ def short(name: str) -> str:
 
 
 def kernel_kind(name: str) -> Optional[str]:
-    """The kind of a Pallas call from its instruction, or None: by the
-    number and types of its results, and for flash attention's single
-    bf16 result by its operands (q, k, v for the forward; q, k, v, do,
-    lse, delta for dq)."""
+    """The kind of a Pallas call from its instruction, or None: the kernel
+    family whose ``WRAPPER`` launched it tells it from the (dtype, dims)
+    of its results and the text of its operands."""
     instr, _, rest = name.partition(" = ")
     if "custom-call(" not in rest:
         return None
     result, _, operands = rest.partition(" custom-call(")
-    outs = _TYPE.findall(result)
-    dtypes = [t for t, _ in outs]
-    if instr.startswith("%_flash_attention_jit"):
-        if len(outs) == 2:
-            return "flash_fwd_lse" if dtypes[1] == "f32" else "flash_dkv"
-        if len(outs) == 1 and dtypes[0] == "f32":
-            return "flash_delta"
-        if len(outs) == 1:
-            n_in = operands.split("custom_call_target")[0].count("%")
-            return "flash_dq" if n_in >= 6 else "flash_fwd"
-    if instr.startswith("%_ssd_jit"):
-        return {2: "ssd_fwd", 3: "ssd_fwd_states", 4: "ssd_bwd"}.get(len(outs))
+    for family in registry.kernel_families():
+        if instr.startswith("%" + family.WRAPPER):
+            return family.kind(_TYPE.findall(result), operands)
     return None
 
 

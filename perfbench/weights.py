@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from perfbench import registry
+
 Tree = Dict[str, Any]
 
 
@@ -39,48 +41,20 @@ def padded_vocab(vocab: int) -> int:
     return ((vocab + 255) // 256) * 256
 
 
-def layer_spec(hf: Dict[str, Any]) -> Dict[str, Tuple[Tuple[int, ...], str, str]]:
-    """Per-layer leaves: path -> (shape, dtype, init rule)."""
-    d = hf["hidden_size"]
-    if hf["model_type"] == "llama":
-        h, kv = hf["num_attention_heads"], hf["num_key_value_heads"]
-        dh = d // h
-        f = hf["intermediate_size"]
-        return {
-            "ln1": ((d,), "bfloat16", "gain"),
-            "mixer.wq": ((d, h * dh), "bfloat16", "fan_in"),
-            "mixer.wk": ((d, kv * dh), "bfloat16", "fan_in"),
-            "mixer.wv": ((d, kv * dh), "bfloat16", "fan_in"),
-            "mixer.wo": ((h * dh, d), "bfloat16", "fan_in"),
-            "ln2": ((d,), "bfloat16", "gain"),
-            "ffn.wg": ((d, f), "bfloat16", "fan_in"),
-            "ffn.wu": ((d, f), "bfloat16", "fan_in"),
-            "ffn.wd": ((f, d), "bfloat16", "fan_in"),
-        }
-    if hf["model_type"] == "mamba2":
-        s = hf["ssm_cfg"]
-        d_in = s["expand"] * d
-        nh = d_in // s["headdim"]
-        gn = s["ngroups"] * s["d_state"]
-        conv = d_in + 2 * gn
-        return {
-            "ln1": ((d,), "bfloat16", "gain"),
-            "mixer.in_proj": ((d, 2 * d_in + 2 * gn + nh), "bfloat16",
-                              "fan_in"),
-            "mixer.conv_w": ((s["d_conv"], conv), "bfloat16", "conv"),
-            "mixer.conv_b": ((conv,), "bfloat16", "small"),
-            "mixer.A_log": ((nh,), "float32", "a_log"),
-            "mixer.D": ((nh,), "float32", "skip"),
-            "mixer.dt_bias": ((nh,), "float32", "dt_bias"),
-            "mixer.norm": ((d_in,), "bfloat16", "gain"),
-            "mixer.out_proj": ((d_in, d), "bfloat16", "fan_in"),
-        }
-    raise ValueError(f"no weight layout for model_type {hf['model_type']!r}")
+def layer_spec(hf: Dict[str, Any]
+               ) -> Dict[str, Tuple[Tuple[int, ...], str, Any]]:
+    """Per-layer leaves: path -> (shape, dtype, init rule), from the
+    architecture's module (``registry.arch``); a rule is the name of one
+    that :func:`_leaf` knows, or a function (key, shape) -> float32
+    values."""
+    return registry.arch(hf["model_type"]).layer_spec(hf)
 
 
 def _leaf(key, shape, dtype, rule):
     f32 = jnp.float32
-    if rule == "fan_in":
+    if callable(rule):
+        v = rule(key, shape)
+    elif rule == "fan_in":
         std = 1.0 / math.sqrt(shape[-2])
         v = jax.random.truncated_normal(key, -2.0, 2.0, shape, f32) * std
     elif rule == "embed":
@@ -89,16 +63,6 @@ def _leaf(key, shape, dtype, rule):
         v = jax.random.normal(key, shape, f32) * 0.05
     elif rule == "small":
         v = jax.random.normal(key, shape, f32) * 0.02
-    elif rule == "conv":
-        v = jax.random.normal(key, shape, f32) / math.sqrt(shape[-2])
-    elif rule == "a_log":
-        v = jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0))
-    elif rule == "skip":
-        v = jax.random.uniform(key, shape, f32, 0.5, 1.5)
-    elif rule == "dt_bias":       # softplus^-1 of dt ~ log-uniform [1e-3, 1e-1]
-        dt = jnp.exp(jax.random.uniform(key, shape, f32, math.log(1e-3),
-                                        math.log(1e-1)))
-        v = dt + jnp.log(-jnp.expm1(-dt))
     else:
         raise ValueError(rule)
     return v.astype(dtype)
@@ -127,26 +91,27 @@ def init_params(hf: Dict[str, Any], key) -> Tree:
     return {
         "embed": {"tok": _leaf(k("embed.tok"),
                                (padded_vocab(hf["vocab_size"]), d),
-                               "bfloat16", "embed")},
+                               hf["torch_dtype"], "embed")},
         "groups": [[_nest(layer)]],
-        "final_norm": _leaf(k("final_norm"), (d,), "bfloat16", "gain"),
+        "final_norm": _leaf(k("final_norm"), (d,), hf["torch_dtype"],
+                            "gain"),
     }
 
 
 def init_state(hf: Dict[str, Any], key) -> Tree:
-    """Params plus the AdamW state at step 0 (f32 moments at zero, an f32
-    master copy of the bf16 params)."""
+    """Params plus the AdamW state at step 0 (f32 moments at zero, and an
+    f32 master copy of the params where any is of a lower precision, as
+    the trainer keeps one)."""
     params = init_params(hf, key)
     zeros = jax.tree.map(lambda t: jnp.zeros(t.shape, jnp.float32), params)
-    return {"params": params,
-            "opt": {"mu": zeros, "nu": jax.tree.map(jnp.zeros_like, zeros),
-                    "master": jax.tree.map(lambda t: t.astype(jnp.float32),
-                                           params)},
-            "step": jnp.zeros((), jnp.int32)}
+    opt = {"mu": zeros, "nu": jax.tree.map(jnp.zeros_like, zeros)}
+    if any(t.dtype != jnp.float32 for t in jax.tree.leaves(params)):
+        opt["master"] = jax.tree.map(lambda t: t.astype(jnp.float32), params)
+    return {"params": params, "opt": opt, "step": jnp.zeros((), jnp.int32)}
 
 
 def master_params(hf: Dict[str, Any], key) -> Tree:
-    """The f32 parameters at step 0: the bf16 weights, widened."""
+    """The f32 parameters at step 0: the weights, widened."""
     return jax.tree.map(lambda t: t.astype(jnp.float32),
                         init_params(hf, key))
 
